@@ -32,6 +32,7 @@ import jax
 
 from repro import configs
 from repro.core import policy as pol
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import lm
 from repro.serve import predict_table
 from repro.serve import traffic as tf
@@ -121,9 +122,9 @@ def main(argv=None) -> int:
     print(f"trace: {args.trace}, {trace.n_requests} requests over "
           f"{trace.ticks} ticks (seed {args.seed})")
 
+    enable_compile_cache()
     cfg = configs.get_smoke(args.arch)
-    params = lm.init_params(cfg, jax.random.PRNGKey(0))
-    qparams = lm.quantize_params(params, cfg)
+    qparams = lm.init_serve_params(cfg, jax.random.PRNGKey(0))
 
     def slo(preds):
         if args.window_ticks:

@@ -10,7 +10,7 @@ batch/cache placement rules used by the launchers and the serving engine.
 from repro.dist import api, placement, sharding            # noqa: F401
 from repro.dist.api import (active_mesh, constrain,        # noqa: F401
                             constrain_heads, dp_size, logical_to_mesh,
-                            manual_mode, mesh_axes_for, shard_map_compat,
-                            tp_size, use_mesh)
+                            manual_mode, mesh_axes_for, tp_size,
+                            use_mesh)
 from repro.dist.placement import (PlacementPlan,           # noqa: F401
                                   plan_for_controller, plan_placement)

@@ -13,7 +13,8 @@ on CPU tests and single-host launches.
 
 The active mesh is resolved from (in order):
   1. the explicit :func:`use_mesh` context stack (nestable, thread-local);
-  2. jax's own ``with mesh:`` context manager (what launch/dryrun uses).
+  2. jax's own mesh context, ``with jax.set_mesh(mesh):`` (what
+     launch/dryrun uses).
 
 Divisibility fallback: a dimension whose size does not divide the product
 of its mapped mesh axes is REPLICATED (per dimension, not per spec) —
@@ -61,15 +62,12 @@ def use_mesh(mesh):
 
 
 def _jax_context_mesh():
-    """The mesh of an enclosing ``with mesh:`` block, if any."""
-    try:
-        from jax.interpreters import pxla
-        m = pxla.thread_resources.env.physical_mesh
-        if m is not None and not m.empty:
-            return m
-    except Exception:       # noqa: BLE001 — internals moved; degrade to None
-        pass
-    return None
+    """The mesh of an enclosing ``with jax.set_mesh(mesh):`` block, if any.
+
+    The abstract mesh (axis names and sizes) is what jax exposes both
+    inside and outside a trace, and all :func:`constrain` needs."""
+    m = jax.sharding.get_abstract_mesh()
+    return None if m.empty else m
 
 
 def active_mesh():
@@ -186,24 +184,6 @@ def logical_to_mesh(mesh, logical_axes: Sequence[Optional[str]],
 # ---------------------------------------------------------------------------
 # Sharding constraints (no-ops without a mesh)
 # ---------------------------------------------------------------------------
-
-def shard_map_compat(f, *, mesh, in_specs, out_specs, check: bool = False):
-    """``shard_map`` across jax versions.
-
-    The function moved out of ``jax.experimental`` and its replication-
-    check kwarg was renamed ``check_rep`` -> ``check_vma`` along the way.
-    """
-    try:
-        from jax import shard_map as sm
-    except ImportError:                         # older jax
-        from jax.experimental.shard_map import shard_map as sm
-    try:
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_vma=check)
-    except TypeError:
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_rep=check)
-
 
 def constrain(x, logical_axes: Sequence[Optional[str]]):
     """``with_sharding_constraint`` in logical axes; identity off-mesh

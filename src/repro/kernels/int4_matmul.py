@@ -30,10 +30,11 @@ def _kernel(x_ref, wp_ref, s_ref, o_ref, acc_ref, *, k_steps: int,
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     j = pl.program_id(1)
-    wp = wp_ref[...]                                   # (bk, bn) uint8 packed
+    # shift, mask and sign extension run on int32 lanes (Mosaic has no
+    # 8-bit shifts); only the finished int4 values narrow to int8 for the dot
+    wp = wp_ref[...].astype(jnp.int32)                 # (bk, bn) packed bytes
     nib = jnp.where(j < n_half_blocks, wp & 0xF, (wp >> 4) & 0xF)
-    w = nib.astype(jnp.int8)
-    w = jnp.where(w >= 8, w - 16, w)                   # sign-extend int4
+    w = jnp.where(nib >= 8, nib - 16, nib).astype(jnp.int8)   # sign-extend
 
     acc_ref[...] += jax.lax.dot_general(
         x_ref[...], w,

@@ -6,13 +6,16 @@ dry-run) only through the dispatchers here — ``serve_linear`` for int8 /
 packed-int4 containers (scalar, traced, or per-row bits), and
 ``flash_attention`` for long-sequence attention.
 
-``use_pallas()`` is True only on real TPU backends; elsewhere (this CPU
-container, and inside the 512-device dry-run) the mathematically identical
-ref path lowers through XLA, so compiled-artifact analysis reflects the
-same algorithm.  Kernel *numerics* are validated against ref in
-tests/test_kernels.py with interpret=True; setting ``REPRO_PALLAS=interpret``
-in the environment routes every dispatcher through interpret-mode Pallas
-(the CI kernel job).
+``use_pallas()`` is True only on real TPU backends; elsewhere (CPU test
+runs, and inside the 512-device dry-run) the mathematically identical ref
+path lowers through XLA, so compiled-artifact analysis reflects the same
+algorithm.  Kernel *numerics* are validated against ref in
+tests/test_kernels.py with interpret=True.  Two test-only switches
+override the backend's choice: ``REPRO_PALLAS=interpret`` in the
+environment routes every dispatcher through interpret-mode Pallas (the CI
+kernel job), and :func:`set_force_pallas` forces either path in-process.
+:func:`pallas_overrides` names whichever is active, so a run that must
+prove the TPU path (``chip_smoke.py``) can refuse them.
 
 Per-precision specializations are cached by (n_planes, block shape) via
 jit's static-arg cache: switching a layer between 2/4/8 bits after warmup
@@ -53,10 +56,9 @@ from repro.kernels.bitplane_matmul import bitplane_matmul as _bitplane_pallas
 from repro.kernels.quant_matmul import quant_matmul as _quant_pallas
 from repro.kernels.int4_matmul import int4_matmul as _int4_pallas
 
-_FORCE: Optional[bool] = None  # tests set this to route through interpret
-_INTERPRET = os.environ.get("REPRO_PALLAS", "").lower() == "interpret"
-if _INTERPRET:
-    _FORCE = True
+INTERPRET_ENV = os.environ.get("REPRO_PALLAS", "").lower() == "interpret"
+_FORCE: Optional[bool] = True if INTERPRET_ENV else None
+_INTERPRET = INTERPRET_ENV
 
 # Distinct weight bit-widths the grouped per-row path specializes for.
 BIT_FAMILIES = (2, 3, 4, 6, 8)
@@ -76,6 +78,19 @@ def use_pallas() -> bool:
     if _FORCE is not None:
         return _FORCE
     return jax.default_backend() == "tpu"
+
+
+def pallas_overrides() -> list:
+    """The test-only switches currently overriding the backend's choice
+    of kernel path (empty on a plain run)."""
+    out = []
+    if os.environ.get("REPRO_PALLAS"):
+        out.append(f"REPRO_PALLAS={os.environ['REPRO_PALLAS']}")
+    if _FORCE is not None:
+        out.append(f"set_force_pallas({_FORCE})")
+    if _INTERPRET:
+        out.append("interpret mode")
+    return out
 
 
 def _interp(flag: bool) -> bool:
@@ -232,11 +247,11 @@ def int4_matmul(x_q: jnp.ndarray, w_packed: jnp.ndarray, scale: jnp.ndarray,
                 *, out_dtype=jnp.float32, interpret: bool = False) -> jnp.ndarray:
     """int8 (M,K) @ halves-packed uint8 (K,N/2) with fused dequant.
 
-    Invalid operand shapes raise ``ValueError``.  When K or the packed
-    column count does not tile (padding packed columns would split the
-    low/high nibble halves inconsistently), the call falls back to the
-    XLA ref path instead of crashing — model dims are 128-multiples, so
-    the Pallas path covers the hot shapes.
+    Invalid operand shapes raise ``ValueError``.  K and the packed width
+    are never padded (padding packed columns would split the low/high
+    nibble halves): a dim that does not tile in 128 takes one full-width
+    block.  The TPU needs lane-aligned column blocks, so there a packed
+    width N/2 that is not a multiple of 128 raises with the shapes.
     """
     interpret = _interp(interpret)
     M, K = x_q.shape
@@ -253,9 +268,13 @@ def int4_matmul(x_q: jnp.ndarray, w_packed: jnp.ndarray, scale: jnp.ndarray,
     scale = jnp.broadcast_to(scale.reshape(1, -1), (1, N))
     if not (use_pallas() or interpret):
         return kref.int4_matmul_ref(x_q, w_packed, scale, out_dtype)
-    bm, bn, bk = _blocks_for(M, N, K)
-    if K % bk or N % bn or (N // 2) % bn:
-        return kref.int4_matmul_ref(x_q, w_packed, scale, out_dtype)
+    bm = _block_dim(M)
+    bk = 128 if K % 128 == 0 else K
+    bn = 128 if (N // 2) % 128 == 0 else N // 2
+    if bn % 128 and not interpret:
+        raise ValueError(
+            f"int4_matmul: packed weights {w_packed.shape} (N={N}) need "
+            f"N/2 to be a multiple of 128 for the TPU kernel")
     xp = _pad_to(x_q, (bm, bk))
     out = _int4_pallas(xp, w_packed, scale, out_dtype=out_dtype,
                        bm=bm, bn=bn, bk=bk, interpret=interpret)

@@ -1,11 +1,14 @@
 """Multi-pod dry-run: lower + compile every (arch x shape) cell on the
 production mesh and extract roofline inputs from the compiled artifact.
 
-MUST set the fake-device flag before ANY jax import (jax locks the device
-count on first init):
+A CPU lowering tool: it pins the CPU platform (for itself and, through
+the environment, every ``--all`` child) so it never claims an
+accelerator, and it MUST set the fake-device flag before ANY jax import
+(jax locks the device count on first init):
 """
 import os
 import re
+os.environ["JAX_PLATFORMS"] = "cpu"
 # Drop any inherited device-count flag (CI exports =8 for the mesh tests;
 # whichever flag comes LAST wins inside XLA) before forcing 512.
 _inherited = re.sub(r"--xla_force_host_platform_device_count=\d+", "",
@@ -165,7 +168,7 @@ def lower_cell(arch: str, shape_name: str, multi_pod: bool,
     mesh = make_production_mesh(multi_pod=multi_pod)
     t0 = time.time()
 
-    with mesh:
+    with jax.set_mesh(mesh):
         if shape.kind == "train":
             ocfg = sp.optimizer_for(cfg)
             tcfg = TrainConfig(optimizer=ocfg, n_accum=accum_for(cfg, shape))

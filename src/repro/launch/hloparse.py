@@ -28,6 +28,7 @@ _DTYPE_BYTES = {"f64": 8, "s64": 8, "u64": 8, "c64": 8, "f32": 4, "s32": 4,
                 "s8": 1, "u8": 1, "pred": 1, "f8e4m3fn": 1, "f8e5m2": 1}
 
 _SHAPE_RE = re.compile(r"([a-z0-9]+)\[([\d,]*)\]")
+_OPERAND_RE = re.compile(r"%([\w.\-]+)")
 _DEF_RE = re.compile(r"^\s*(?:ROOT\s+)?%([\w.\-]+)\s*=\s*(\([^)]*\)|[a-z0-9]+\[[\d,]*\]\S*)\s+([\w\-]+)\(")
 _CALLED_RE = re.compile(r"(?:calls=|to_apply=|body=)%([\w.\-]+)")
 _COND_RE = re.compile(r"condition=%([\w.\-]+)")
@@ -41,19 +42,16 @@ _NO_BYTES_OPS = {"parameter", "tuple", "get-tuple-element", "bitcast",
 
 
 def cost_analysis_dict(compiled) -> dict:
-    """Normalize ``compiled.cost_analysis()`` across jax versions (older
-    jaxlibs return a one-element list of dicts, newer return the dict)."""
-    ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return dict(ca or {})
+    """``compiled.cost_analysis()`` as a plain dict (empty when the
+    backend reports nothing)."""
+    return dict(compiled.cost_analysis() or {})
 
 
 def _operand_segment(line: str, op: str) -> str:
     """The balanced-paren operand list of ``op`` on this line.
 
-    Operands are printed WITH their types (``dot(f32[64,256]{1,0} %a, …)``)
-    and tuple types nest parens, so a greedy regex won't do.
+    Operands are printed as bare names (``dot(%a, %b)``); the parens are
+    matched by depth so nested tuples or calls in the list stay inside.
     """
     i = line.find(" " + op + "(")
     if i < 0:
@@ -128,16 +126,17 @@ def _parse_computations(hlo: str) -> Dict[str, List[str]]:
     return comps
 
 
-def _dot_flops(line: str, result_type: str) -> Tuple[float, bool]:
+def _dot_flops(line: str, result_type: str,
+               types: Dict[str, str]) -> Tuple[float, bool]:
     """(flops, is_int8). flops = 2 * |result| * prod(contracted lhs dims)."""
     info = _shape_info(result_type)
     if not info:
         return 0.0, False
     result_n = info[0][1]
-    seg = _operand_segment(line, "dot")
+    names = _OPERAND_RE.findall(_operand_segment(line, "dot"))
     contract = 1
     lhs_dt = None
-    m = _SHAPE_RE.search(seg)               # lhs type is inline in operands
+    m = _SHAPE_RE.search(types.get(names[0], "")) if names else None
     if m:
         lhs_dt = m.group(1)
         lhs_dims = [int(d) for d in m.group(2).split(",") if d]
@@ -163,12 +162,17 @@ def analyze(hlo: str) -> Cost:
             return cache[name]
         cost = Cost()
         cache[name] = cost                       # cycle guard
-        lines = comps.get(name, [])
-        for line in lines:
-            d = _DEF_RE.match(line)
-            if not d:
-                continue
-            _, result_type, op = d.groups()
+        defs = [d.groups() + (line,) for line in comps.get(name, [])
+                for d in [_DEF_RE.match(line)] if d]
+        # operands print as bare names: resolve them through this
+        # computation's definitions (parameters included)
+        types = {var: rtype for var, rtype, _, _ in defs}
+
+        def operand_bytes(line: str, op: str) -> float:
+            return sum(_bytes_of(types.get(v, "")) for v in
+                       _OPERAND_RE.findall(_operand_segment(line, op)))
+
+        for _, result_type, op, line in defs:
             if op == "while":
                 body = _CALLED_RE.search(line)
                 trip = _TRIP_RE.search(line)
@@ -194,13 +198,13 @@ def analyze(hlo: str) -> Cost:
                     part.add(inner)
                     part["bytes"] = 0.0
                     cost.add(part)
-                cost["bytes"] += _bytes_of(result_type) + _operand_bytes(
+                cost["bytes"] += _bytes_of(result_type) + operand_bytes(
                     line, op)
                 continue
             if op == "dot":
-                fl, is8 = _dot_flops(line, result_type)
+                fl, is8 = _dot_flops(line, result_type, types)
                 cost["flops_int8" if is8 else "flops"] += fl
-                b = _bytes_of(result_type) + _operand_bytes(line, op)
+                b = _bytes_of(result_type) + operand_bytes(line, op)
                 cost["bytes"] += b
                 cost["bytes_dot"] += b
                 continue
@@ -216,14 +220,9 @@ def analyze(hlo: str) -> Cost:
                     break
             if op in _NO_BYTES_OPS or op.endswith("-done"):
                 continue
-            cost["bytes"] += _bytes_of(result_type) + _operand_bytes(
+            cost["bytes"] += _bytes_of(result_type) + operand_bytes(
                 line, op)
         return cost
-
-    def _operand_bytes(line: str, op: str) -> float:
-        # Operand types are printed inline in scheduled HLO; sum them
-        # directly rather than resolving names through the symbol table.
-        return _bytes_of(_operand_segment(line, op))
 
     return comp_cost("__entry__" if "__entry__" in comps
                      else next(iter(comps)))

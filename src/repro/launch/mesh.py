@@ -11,12 +11,20 @@ DCI — kept to one (optionally int8-compressed) all-reduce per step.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def make_auto_mesh(shape, axes):
+    """``jax.make_mesh`` with ``Auto`` axes: the models place arrays with
+    ``with_sharding_constraint``, which only accepts Auto mesh axes
+    (``make_mesh`` defaults to Explicit)."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_auto_mesh(shape, axes)
 
 
 def make_host_mesh(model: int = 1):
@@ -24,7 +32,7 @@ def make_host_mesh(model: int = 1):
     n = len(jax.devices())
     assert n % model == 0, \
         f"model={model} must divide the {n} visible devices"
-    return jax.make_mesh((n // model, model), ("data", "model"))
+    return make_auto_mesh((n // model, model), ("data", "model"))
 
 
 # TPU v5e hardware constants (roofline denominators)

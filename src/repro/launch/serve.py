@@ -38,6 +38,7 @@ from repro.core import policy as pol
 from repro.data.pipeline import make_batch
 from repro.models import lm
 from repro.serve import aggregate, predict_table
+from repro.launch.compile_cache import enable_compile_cache
 from repro.serve.engine import ServeEngine
 from repro.train.checkpoint import latest_step, restore_checkpoint
 
@@ -60,6 +61,21 @@ def fluid_controller(cfg, n: int, args) -> pol.FluidController:
         head=lm.head_gemm_dims(cfg))
     return pol.FluidController(base.configs, preds, n, budget_axis="edp",
                                slo=args.slo_edp, window=args.requests)
+
+
+def load_serve_params(cfg, ckpt_dir: str = "", seed: int = 0) -> dict:
+    """Serve-form params: trained weights from ``ckpt_dir`` when it holds
+    a checkpoint, else random weights from ``seed``.  Neither path keeps
+    the bf16 train-form tree once the int8 containers exist: a fresh
+    model is initialized straight into serve form, and a restored tree
+    is dropped as soon as it is quantized."""
+    if ckpt_dir and latest_step(ckpt_dir) is not None:
+        target = jax.eval_shape(lambda k: lm.init_params(cfg, k),
+                                jax.random.PRNGKey(seed))
+        restored, step = restore_checkpoint(ckpt_dir, {"params": target})
+        print(f"[serve] restored weights from step {step}")
+        return lm.quantize_params(restored.pop("params"), cfg)
+    return lm.init_serve_params(cfg, jax.random.PRNGKey(seed))
 
 
 def main() -> None:
@@ -98,18 +114,12 @@ def main() -> None:
     if args.budgets is None:
         args.budgets = [2.0, 0.5]
 
+    enable_compile_cache()
     cfg = (configs.get_smoke(args.arch) if args.smoke
            else configs.get(args.arch))
     if args.kv_bits:
         cfg = cfg.with_(kv_cache_bits=args.kv_bits)
-    params = lm.init_params(cfg, jax.random.PRNGKey(0))
-    if args.ckpt_dir and latest_step(args.ckpt_dir) is not None:
-        target = {"params": jax.tree.map(
-            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), params)}
-        restored, step = restore_checkpoint(args.ckpt_dir, target)
-        params = restored["params"]
-        print(f"[serve] restored weights from step {step}")
-    qparams = lm.quantize_params(params, cfg)
+    qparams = load_serve_params(cfg, args.ckpt_dir)
 
     n = lm.n_bit_slots(cfg)
     if args.batch:

@@ -22,6 +22,7 @@ import jax
 from repro import configs, dist
 from repro.data.pipeline import SyntheticLM
 from repro.dist import sharding as shd
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh
 from repro.models import lm
 from repro.optim.adamw import AdamWConfig, adamw_init
@@ -49,6 +50,7 @@ def main() -> None:
     ap.add_argument("--ckpt-every", type=int, default=50)
     ap.add_argument("--log-every", type=int, default=10)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = (configs.get_smoke(args.arch) if args.smoke
            else configs.get(args.arch))

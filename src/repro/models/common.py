@@ -98,7 +98,13 @@ def _train_linear(p: dict, x: jnp.ndarray, wbits, abits) -> jnp.ndarray:
     # bf16 — the dominant train all-reduces were f32 activation-shaped
     # cotangents from an f32 round-trip here (§Perf iter 6)
     w = bf.fake_quant(p["w"], wbits, axis=0)
-    xq = bf.fake_quant(x.astype(DTYPE), abits)
+    # one activation scale per sequence (reduce every axis but the batch),
+    # as the per-row serve path does: a row's quantization never depends
+    # on its batch-mates, so micro-batched gradient accumulation computes
+    # the same forward as the full batch.  2-D inputs are one sequence
+    # (the per-row vmap path) and keep a single scale.
+    axes = tuple(range(1, x.ndim)) if x.ndim > 2 else None
+    xq = bf.fake_quant(x.astype(DTYPE), abits, axis=axes)
     y = jnp.einsum("...k,kn->...n", xq, w,
                    preferred_element_type=DTYPE).astype(jnp.float32)
     if "b" in p:

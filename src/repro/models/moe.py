@@ -191,11 +191,11 @@ def _apply_moe_shard_map(p, xf, topi, topv, cfg, wbits, abits, mesh, C_shard):
     ex_specs = jax.tree.map(
         lambda l: P("model", dp, None) if _is_big(l)
         else P("model", None, None), ex)
-    return dist.api.shard_map_compat(
+    return jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(dp, None), P(dp, None), P(dp, None), ex_specs),
         out_specs=P(dp, None),
-        check=False,
+        check_vma=False,
     )(xf, topi, topv, ex)
 
 

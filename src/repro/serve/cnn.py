@@ -127,11 +127,11 @@ class CNNServeEngine(ServeRuntime):
                 with dist.manual_mode():
                     return _fwd(qp, x, wmat, amat)
 
-            self._fwd = jax.jit(dist.shard_map_compat(
+            self._fwd = jax.jit(jax.shard_map(
                 _fwd_manual, mesh=self.mesh,
                 in_specs=(P(), P(dpx, None, None, None),
                           P(dpx, None), P(dpx, None)),
-                out_specs=P(dpx, None)))
+                out_specs=P(dpx, None), check_vma=False))
         else:
             self._fwd = jax.jit(_fwd)
 

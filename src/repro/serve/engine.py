@@ -389,6 +389,17 @@ class ServeEngine(ServeRuntime):
 
             dpx = self._dp_exec
 
+            def _prefill_row_manual(*args):
+                with dist.manual_mode():
+                    return _prefill_row(*args)
+
+            # GSPMD cannot partition a Pallas (Mosaic) kernel, and one row
+            # does not split across dp: every device prefills the row
+            # whole from replicated weights (inputs and outputs replicated)
+            self._prefill_row = jax.jit(jax.shard_map(
+                _prefill_row_manual, mesh=self.mesh, in_specs=P(),
+                out_specs=P(), check_vma=False))
+
             def _decode_scan_manual(*args):
                 # trace-time flag: constrain() inside the body must
                 # no-op (mesh axes are consumed by the shard_map)
@@ -400,13 +411,14 @@ class ServeEngine(ServeRuntime):
             # split on its batch dim — cache leaves all carry batch at
             # dim 1, so one prefix spec covers the whole pytree
             self._decode_scan_sh = jax.jit(
-                dist.shard_map_compat(
+                jax.shard_map(
                     _decode_scan_manual, mesh=self.mesh,
                     in_specs=(P(), P(dpx, None), P(dpx), P(None, dpx),
                               P(dpx, None), P(dpx, None), P(dpx),
                               P(dpx), P()),
                     out_specs=(P(dpx, None), P(dpx), P(None, dpx),
-                               P(dpx, None))),
+                               P(dpx, None)),
+                    check_vma=False),
                 donate_argnums=(3,))
         self._decode_one = jax.jit(_decode_one, donate_argnums=(3,))
         self._draft = jax.jit(_draft_scan, donate_argnums=(3,))

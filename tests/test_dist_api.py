@@ -13,6 +13,7 @@ from jax.sharding import PartitionSpec as P
 
 from repro import dist
 from repro.dist import api, sharding as shd
+from repro.launch.mesh import make_host_mesh
 
 
 def _padded(spec, n: int):
@@ -24,7 +25,7 @@ def _host_mesh(model: int = 1):
     n = len(jax.devices())
     if n % max(model, 1) != 0:
         pytest.skip(f"{n} devices not divisible by model={model}")
-    return jax.make_mesh((n // model, model), ("data", "model"))
+    return make_host_mesh(model)
 
 
 # ---------------------------------------------------------------------------
@@ -50,7 +51,7 @@ def test_use_mesh_nesting():
 
 def test_jax_context_manager_is_seen():
     mesh = _host_mesh()
-    with mesh:
+    with jax.set_mesh(mesh):
         assert api.active_mesh() is not None
         assert api.dp_size() == mesh.shape["data"]
     assert api.active_mesh() is None
@@ -59,7 +60,7 @@ def test_jax_context_manager_is_seen():
 def test_stack_wins_over_jax_context():
     mesh = _host_mesh()
     explicit = _host_mesh()
-    with mesh, api.use_mesh(explicit):
+    with jax.set_mesh(mesh), api.use_mesh(explicit):
         assert api.active_mesh() is explicit
 
 
@@ -113,7 +114,7 @@ def test_constrain_places_data_on_mesh():
     mesh = _host_mesh()
     dp = mesh.shape["data"]
     x = jnp.arange(dp * 4.0).reshape(dp, 4)
-    with mesh:
+    with jax.set_mesh(mesh):
         y = jax.jit(lambda t: dist.constrain(t, ("dp", None)))(x)
     np.testing.assert_array_equal(np.asarray(y), np.asarray(x))
     if dp > 1:
@@ -130,7 +131,7 @@ def test_constrain_jit_zero_retraces():
         return dist.constrain(t * 2.0, ("dp", None))
 
     jitted = jax.jit(f)
-    with mesh:
+    with jax.set_mesh(mesh):
         for i in range(3):
             x = jnp.full((dp * 2, 3), float(i))
             out = jitted(x)
@@ -144,7 +145,7 @@ def test_constrain_heads_picks_axis():
     if tp == 1:
         pytest.skip("single device")
     x = jnp.zeros((2, 1, tp, 4 * tp))
-    with mesh:
+    with jax.set_mesh(mesh):
         y_head = jax.jit(lambda t: dist.constrain_heads(t, 2, 3, True))(x)
         y_alt = jax.jit(lambda t: dist.constrain_heads(t, 2, 3, False))(x)
     assert _padded(y_head.sharding.spec, 4) == (None, None, "model", None)

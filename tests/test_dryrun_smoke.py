@@ -33,6 +33,7 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import sys
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
+from repro.launch.mesh import make_auto_mesh
 from repro.train.checkpoint import save_checkpoint, restore_checkpoint
 
 d = sys.argv[1]
@@ -40,12 +41,12 @@ tree = {"w": jnp.arange(64 * 16, dtype=jnp.float32).reshape(64, 16),
         "b": jnp.ones((16,), jnp.bfloat16)}
 
 # save on mesh A (4x2)
-mesh_a = jax.make_mesh((4, 2), ("data", "model"))
+mesh_a = make_auto_mesh((4, 2), ("data", "model"))
 w_a = jax.device_put(tree["w"], NamedSharding(mesh_a, P("data", "model")))
 save_checkpoint(d, 3, {"w": w_a, "b": tree["b"]})
 
 # restore on mesh B (2x4) — elastic rescale
-mesh_b = jax.make_mesh((2, 4), ("data", "model"))
+mesh_b = make_auto_mesh((2, 4), ("data", "model"))
 target = {"w": jax.ShapeDtypeStruct((64, 16), jnp.float32),
           "b": jax.ShapeDtypeStruct((16,), jnp.bfloat16)}
 shard = {"w": NamedSharding(mesh_b, P("data", "model")),
@@ -74,9 +75,9 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P
 from repro.optim.compress import compress_psum
-from repro.dist.api import shard_map_compat
+from repro.launch.mesh import make_auto_mesh
 
-mesh = jax.make_mesh((8,), ("pod",))
+mesh = make_auto_mesh((8,), ("pod",))
 rng = np.random.default_rng(0)
 g_all = jnp.asarray(rng.normal(size=(8, 64, 32)), jnp.float32)
 
@@ -84,8 +85,8 @@ def step(g, e):
     avg, new_e = compress_psum({"w": g}, {"w": e}, "pod")
     return avg["w"], new_e["w"]
 
-f = shard_map_compat(step, mesh=mesh, in_specs=(P("pod"), P("pod")),
-                     out_specs=(P("pod"), P("pod")), check=False)
+f = jax.shard_map(step, mesh=mesh, in_specs=(P("pod"), P("pod")),
+                  out_specs=(P("pod"), P("pod")), check_vma=False)
 
 e = jnp.zeros_like(g_all)
 total_err = []

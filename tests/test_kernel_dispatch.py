@@ -175,8 +175,9 @@ def test_blocks_for_shrinks_all_dims():
 
 
 def test_int4_matmul_unaligned_falls_back(rng):
-    """Packed-column padding would split nibble halves; the dispatcher
-    must fall back to ref instead of crashing (the old assert)."""
+    """Packed-column padding would split nibble halves; an unaligned
+    packed width runs the kernel on one full-width column block (exact
+    against the int64 oracle) instead of crashing or leaving the kernel."""
     M, K, N = 16, 64, 72                    # N/2 = 36 does not tile
     x = rng.integers(-127, 128, (M, K)).astype(np.int8)
     q4 = rng.integers(-8, 8, (K, N)).astype(np.int8)
@@ -186,6 +187,19 @@ def test_int4_matmul_unaligned_falls_back(rng):
                           interpret=True)
     want = (x.astype(np.int64) @ q4.astype(np.int64)).astype(np.float32) * s
     np.testing.assert_allclose(np.asarray(got), want, rtol=1e-5)
+
+
+def test_int4_matmul_unaligned_raises_for_tpu(rng):
+    """Off interpret mode (the TPU kernel), an unaligned packed width is
+    refused with its shapes before anything compiles."""
+    x = jnp.asarray(rng.integers(-10, 10, (8, 64)).astype(np.int8))
+    packed = jnp.zeros((64, 36), jnp.uint8)             # N/2 = 36
+    ops.set_force_pallas(True, interpret=False)
+    try:
+        with pytest.raises(ValueError, match=r"\(64, 36\)"):
+            ops.int4_matmul(x, packed, jnp.ones((1, 72)))
+    finally:
+        ops.set_force_pallas(None, interpret=ops.INTERPRET_ENV)
 
 
 def test_int4_matmul_bad_shapes_raise(rng):

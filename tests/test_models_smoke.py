@@ -6,6 +6,7 @@ import jax.numpy as jnp
 import pytest
 
 from repro import configs
+from repro.core import bitfluid as bf
 from repro.models import lm
 
 B, S = 2, 32
@@ -65,6 +66,31 @@ def test_serve_smoke(arch):
         qparams, tok, jnp.asarray(t0), cache)
     assert logits2.shape == (B, 1, cfg.vocab_size)
     assert np.isfinite(np.asarray(logits2, np.float32)).all()
+
+
+@pytest.mark.parametrize("container", ["int8", "int4"])
+@pytest.mark.parametrize("arch", ["qwen3_4b", "kimi_k2_1t_a32b"])
+def test_init_serve_params_matches_quantize(arch, container):
+    """Serve form straight from the seed (one fused program) == the
+    train-form tree quantized leaf by leaf.  Fused, the compiler may turn
+    the division by the constant qmax into a reciprocal multiply: scales
+    then differ in the last ulp and a value on a rounding edge moves by
+    one quantization step, never more."""
+    cfg = configs.get_smoke(arch)
+    want = lm.quantize_params(lm.init_params(cfg, KEY), cfg, container)
+    got = lm.init_serve_params(cfg, KEY, container)
+    assert jax.tree.structure(got) == jax.tree.structure(want)
+    for (path, g), w in zip(jax.tree_util.tree_leaves_with_path(got),
+                            jax.tree.leaves(want)):
+        assert g.dtype == w.dtype and g.shape == w.shape
+        if path[-1].key == "q4":
+            g, w = bf.unpack_int4_halves(g), bf.unpack_int4_halves(w)
+        g, w = np.asarray(g, np.float32), np.asarray(w, np.float32)
+        if path[-1].key in ("q", "q4"):
+            assert np.abs(g - w).max() <= 1
+            assert np.mean(g != w) < 1e-2
+        else:
+            np.testing.assert_allclose(g, w, rtol=1e-6)
 
 
 @pytest.mark.parametrize("arch", ["qwen3_4b", "kimi_k2_1t_a32b",
