@@ -1,0 +1,8 @@
+"""Share of the traced window in which no operation ran on the device, in
+%: 1 - (union of device op intervals) / window, from the trace."""
+
+
+def read(run):
+    if run.trace is None:
+        return None
+    return 100.0 * run.trace.idle_share
