@@ -32,6 +32,7 @@ from typing import Dict, Optional, Tuple
 HOT_SCOPES: Dict[str, Tuple[str, ...]] = {
     "src/repro/serve/engine.py": (
         "ServeEngine._admit",
+        "ServeEngine._admit_request",
         "ServeEngine._step",
         "ServeEngine._decode_tick",
         "ServeEngine._spec_round",

@@ -2,33 +2,99 @@
 
 One cost vocabulary for every serve workload: :class:`RuntimeStats`
 counts compiled-program traces (the zero-retrace proof) and engine-wide
-totals; :class:`CostRecord` is the single per-request record both the LM
-engine (:class:`RequestStats`) and the CNN engine (:class:`ImageStats`)
-specialize — each request carries its resolved precision and the AP cost
-of that precision priced through the paper's calibrated model, so
-latency/energy/EDP read identically across workloads and aggregate with
-:func:`aggregate`; :class:`BitVectorPricer` is the shared cached pricer
-(vector and one-pass matrix forms) whose charges also drive the
-closed-loop :class:`repro.core.policy.FluidController`.
+totals and records the engine's host spans; :class:`CostRecord` is the
+single per-request record both the LM engine (:class:`RequestStats`)
+and the CNN engine (:class:`ImageStats`) specialize — each request
+carries its resolved precision and the AP cost of that precision priced
+through the paper's calibrated model, so latency/energy/EDP read
+identically across workloads and aggregate with :func:`aggregate`;
+:class:`BitVectorPricer` is the shared cached pricer (vector and
+one-pass matrix forms) whose charges also drive the closed-loop
+:class:`repro.core.policy.FluidController`.
 """
 from __future__ import annotations
 
+import collections
 import dataclasses
-from typing import Dict, Iterable, List, Optional, Sequence, Tuple
+import time
+from typing import Dict, Iterable, List, NamedTuple, Optional, Sequence, Tuple
 
+import jax
 import numpy as np
 
 from repro.apsim import metrics as apm
 
+SPAN_RING = 65_536      # events the span ring keeps (oldest dropped first)
+
+
+class SpanEvent(NamedTuple):
+    """One recorded host span, or an instant mark (``t1_ns == t0_ns``).
+
+    Times are ``time.perf_counter_ns()``.  ``seq`` numbers every event of
+    a :class:`RuntimeStats` in the order it opened; ``parent`` is the
+    ``seq`` of the enclosing span (-1 at the root).  ``tick`` is the
+    scheduler tick in progress (``RuntimeStats.clock``, which is the
+    runtime's scheduler clock), ``rid`` the request the span is for (-1
+    when it is not one request's)."""
+    name: str
+    t0_ns: int
+    t1_ns: int
+    parent: int
+    rid: int
+    tick: int
+    seq: int
+
+
+class _Span:
+    """Context manager behind :meth:`RuntimeStats.span`: records one
+    :class:`SpanEvent` on exit and opens the same name as a profiler
+    ``TraceAnnotation``, so the span also lands in any profiler trace
+    beside the device ops."""
+    __slots__ = ("stats", "name", "rid", "seq", "parent", "tick", "t0",
+                 "ann")
+
+    def __init__(self, stats: "RuntimeStats", name: str, rid: int) -> None:
+        self.stats = stats
+        self.name = name
+        self.rid = rid
+
+    def __enter__(self) -> "_Span":
+        st = self.stats
+        self.seq = st._seq
+        st._seq += 1
+        self.parent = st._open[-1] if st._open else -1
+        self.tick = st.clock
+        st._open.append(self.seq)
+        self.ann = jax.profiler.TraceAnnotation(self.name)
+        self.ann.__enter__()
+        self.t0 = time.perf_counter_ns()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        t1 = time.perf_counter_ns()
+        self.ann.__exit__(*exc)
+        st = self.stats
+        st._open.pop()
+        st.events.append(SpanEvent(self.name, self.t0, t1, self.parent,
+                                   self.rid, self.tick, self.seq))
+
 
 class RuntimeStats:
-    """Engine-wide serving counters; trace counts prove zero-retrace.
+    """Engine-wide serving counters and host spans; trace counts prove
+    zero-retrace.
 
     Compiled programs are counted generically: an engine calls
     ``stats.trace("prefill")`` inside the traced function, and readers
     use the derived ``stats.prefill_traces`` / ``decode_traces`` /
     ``forward_traces`` attributes — any ``<program>_traces`` name reads
-    the counter for ``<program>`` (0 if it never traced).
+    the counter for ``<program>`` (0 if it never traced).  Each trace
+    also leaves a ``trace.<program>`` mark, so a retrace names its tick.
+
+    Spans (``with stats.span("admit.plan"): ...``) and marks are always
+    recorded, into ``events``: a ring of the last :data:`SPAN_RING`
+    :class:`SpanEvent` s.  ``spans_dropped`` counts the events the ring
+    has let go; a reader that needs a whole window refuses when it is
+    non-zero.
     """
 
     def __init__(self) -> None:
@@ -36,15 +102,41 @@ class RuntimeStats:
         self.tokens = 0                 # LM: tokens sampled
         self.admitted = 0               # LM: requests admitted into slots
         self.completed = 0              # LM: requests retired
-        self.batches = 0                # CNN: serve() calls
         self.images = 0                 # CNN: real (unpadded) images served
         self.unserved = 0               # requests left pending at run() exit
         self.ticks = 0                  # scheduler ticks recorded
+        self.clock = 0                  # tick in progress (ServeRuntime._tick)
         self.queue_depth: List[int] = []   # queued requests after each tick
         self.active_depth: List[int] = []  # occupied slots after each tick
+        # LM: rid -> (real prompt tokens, positions computed) of the row
+        # that request prefilled or extended; a full prefix-cache hit
+        # prefills none, a partial one only the tokens past its prefix
+        self.prefill_by_rid: Dict[int, Tuple[int, int]] = {}
+        self.events: collections.deque = collections.deque(maxlen=SPAN_RING)
+        self._seq = 0                   # events opened so far
+        self._open: List[int] = []      # seqs of the spans now open
 
     def trace(self, program: str) -> None:
         self.traces[program] = self.traces.get(program, 0) + 1
+        self.mark("trace." + program)
+
+    def span(self, name: str, rid: int = -1) -> _Span:
+        """Context manager recording one host span (never use inside a
+        jitted body: it would record the trace, not the call)."""
+        return _Span(self, name, rid)
+
+    def mark(self, name: str, rid: int = -1) -> None:
+        """Record an instant event."""
+        t = time.perf_counter_ns()
+        self.events.append(SpanEvent(
+            name, t, t, self._open[-1] if self._open else -1, rid,
+            self.clock, self._seq))
+        self._seq += 1
+
+    @property
+    def spans_dropped(self) -> int:
+        """Events the ring has let go (0 while it holds every one)."""
+        return self._seq - len(self.events) - len(self._open)
 
     def record_tick(self, queued: int, active: int) -> None:
         """One scheduler tick's queue instrumentation (the traffic
@@ -61,7 +153,7 @@ class RuntimeStats:
     def __repr__(self) -> str:          # pragma: no cover - debug aid
         return (f"RuntimeStats(traces={self.traces}, tokens={self.tokens}, "
                 f"admitted={self.admitted}, completed={self.completed}, "
-                f"batches={self.batches}, images={self.images})")
+                f"images={self.images})")
 
 
 @dataclasses.dataclass

@@ -186,7 +186,6 @@ class CNNServeEngine(ServeRuntime):
             self.finish_record(rec.rid)
             stats.append(rec)
         self.stats.admitted += B
-        self.stats.batches += 1
         self.stats.images += B
         return logits_h[:B], stats
 

@@ -640,24 +640,27 @@ class ServeEngine(ServeRuntime):
                                  f"({self.cfg.n_prefix_tokens}, "
                                  f"{self.cfg.d_model})")
         rid = self.next_rid()
-        req = Request(rid, prompt, max_new_tokens,
-                      None if budget_s is None else float(budget_s),
-                      float(temperature), int(top_k), prefix=prefix,
-                      rep_key=rep_key, draft_k=draft_k)
-        record = RequestStats(
-            rid=rid,
-            budget_s=(float(budget_s) if budget_s is not None
-                      else UNCONSTRAINED_BUDGET),
-            prompt_len=int(prompt.shape[0]), submitted_s=time.time())
-        est_scale = 1.0
-        if self._cacheable(req):
-            # admission planner sees the predicted hit: the modeled EDP
-            # is discounted by the predicted cached fraction, so likely
-            # hits admit earlier — they really are cheaper to serve
-            total = prompt.shape[0] + max_new_tokens
-            est_scale = max(total - self.prefix_cache.peek(prompt),
-                            1) / total
-        return self.new_record(record, req, budget_s, est_scale=est_scale)
+        with self.stats.span("request.submit", rid=rid):
+            req = Request(rid, prompt, max_new_tokens,
+                          None if budget_s is None else float(budget_s),
+                          float(temperature), int(top_k), prefix=prefix,
+                          rep_key=rep_key, draft_k=draft_k)
+            record = RequestStats(
+                rid=rid,
+                budget_s=(float(budget_s) if budget_s is not None
+                          else UNCONSTRAINED_BUDGET),
+                prompt_len=int(prompt.shape[0]), submitted_s=time.time())
+            est_scale = 1.0
+            if self._cacheable(req):
+                # admission planner sees the predicted hit: the modeled
+                # EDP is discounted by the predicted cached fraction, so
+                # likely hits admit earlier — they really are cheaper to
+                # serve
+                total = prompt.shape[0] + max_new_tokens
+                est_scale = max(total - self.prefix_cache.peek(prompt),
+                                1) / total
+            return self.new_record(record, req, budget_s,
+                                   est_scale=est_scale)
 
     def _ensure_pool(self) -> lm.CachePool:
         if self.pool is None:
@@ -686,9 +689,20 @@ class ServeEngine(ServeRuntime):
         admitted = []
         while self.queued and pool.free_slots:
             req: Request = self.next_admission()
+            with self.stats.span("admit.request", rid=req.rid):
+                self._admit_request(req, pool)
+            admitted.append(req.rid)
+        return admitted
+
+    def _admit_request(self, req: Request, pool: lm.CachePool) -> None:
+        """One admission, in three spans: ``admit.plan`` (budget, bits,
+        prefix lookup, draft depth, AP pricing), ``prefill.dispatch``
+        (the row's prefill, extension or install, and the first token's
+        sampling) and ``admit.sync`` (that token's read-back)."""
+        S = req.prompt.shape[0]
+        record = self.requests[req.rid]
+        with self.stats.span("admit.plan"):
             slot = pool.alloc()
-            S = req.prompt.shape[0]
-            record = self.requests[req.rid]
             planned = S + req.max_new_tokens
             hit = wv_np = av_np = None
             # resolve the effective budget HOST-side first: the prefix
@@ -726,6 +740,7 @@ class ServeEngine(ServeRuntime):
                     np.mean(hit.entry.wbits))
                 self.prefix_cache.ledger.prefill_edp_saved_js += \
                     record.prefill_edp_saved_js
+        with self.stats.span("prefill.dispatch"):
             tokens = np.zeros((1, self.prefill_len), np.int32)
             tokens[0, :S] = req.prompt
             if hit is not None and hit.full:
@@ -737,12 +752,15 @@ class ServeEngine(ServeRuntime):
             elif hit is not None:
                 # partial hit: install the shared prefix, extend the
                 # rest through the compiled decode-extension program
+                # (a scan of prefill_len positions)
                 logits, row_cache = self._extend_row(
                     self.qparams, jnp.asarray(tokens),
                     hit.entry.row_cache, jnp.asarray(cached, jnp.int32),
                     jnp.asarray(S - cached, jnp.int32), wv, av)
                 pool.write_row(row_cache, slot, S)
                 prefix_len = 0
+                self.stats.prefill_by_rid[req.rid] = (S - cached,
+                                                      self.prefill_len)
                 # refresh only when precision-pure: the extended row
                 # mixes entry bits (prefix) with resolved bits (tail)
                 # unless they match
@@ -760,6 +778,8 @@ class ServeEngine(ServeRuntime):
                 prefix_len = (self.cfg.n_prefix_tokens
                               if self.cfg.family == "vlm" else 0)
                 pool.write_row(row_cache, slot, S + prefix_len)
+                self.stats.prefill_by_rid[req.rid] = (
+                    S + prefix_len, self.prefill_len + prefix_len)
                 if wv_np is not None:   # cacheable miss: store/refresh
                     self.prefix_cache.store(
                         req.prompt, row_cache, logits, wv_np, av_np,
@@ -768,22 +788,21 @@ class ServeEngine(ServeRuntime):
             first = self._sample_first(
                 logits, key, jnp.asarray([req.temperature], jnp.float32),
                 jnp.asarray([req.top_k], jnp.int32))
-            # the unavoidable per-admission sync (eos/stream bookkeeping
-            # needs the sampled token on the host) — exactly one transfer
+        # the unavoidable per-admission sync (eos/stream bookkeeping
+        # needs the sampled token on the host) — exactly one transfer
+        with self.stats.span("admit.sync"):
             first0 = int(jax.device_get(first)[0])
-            record.slot = slot
-            record.tokens.append(first0)
-            self.stats.tokens += 1
-            self.slots.occupy(slot, req.rid, tok=first0,
-                              t=S + prefix_len, budget=record.budget_s,
-                              temp=req.temperature, topk=req.top_k,
-                              remaining=req.max_new_tokens - 1, k=k_req)
-            admitted.append(req.rid)
-            if self.slots["remaining"][slot] <= 0 or (
-                    self.eos_id is not None
-                    and first0 == self.eos_id):
-                self._finish(slot)
-        return admitted
+        record.slot = slot
+        record.tokens.append(first0)
+        self.stats.tokens += 1
+        self.slots.occupy(slot, req.rid, tok=first0,
+                          t=S + prefix_len, budget=record.budget_s,
+                          temp=req.temperature, topk=req.top_k,
+                          remaining=req.max_new_tokens - 1, k=k_req)
+        if self.slots["remaining"][slot] <= 0 or (
+                self.eos_id is not None
+                and first0 == self.eos_id):
+            self._finish(slot)
 
     def _finish(self, slot: int) -> None:
         rid = int(self.slots.rid[slot])
@@ -809,8 +828,9 @@ class ServeEngine(ServeRuntime):
             return self._step()
 
     def _step(self) -> List[int]:
-        self.age_queue()
-        self._admit()
+        with self.stats.span("admit"):
+            self.age_queue()
+            self._admit()
         slots = self.slots
         active = slots.active
         if active.any():
@@ -821,10 +841,11 @@ class ServeEngine(ServeRuntime):
             k_eff = np.where(
                 active, np.minimum(slots["k"], slots["remaining"] - 1),
                 0).astype(np.int64)
-            if k_eff.max() > 0:
-                self._spec_round(active, k_eff)
-            else:
-                self._decode_tick(active)
+            with self.stats.span("decode"):
+                if k_eff.max() > 0:
+                    self._spec_round(active, k_eff)
+                else:
+                    self._decode_tick(active)
         done = self._just_finished
         self._just_finished = []
         return done
@@ -847,34 +868,38 @@ class ServeEngine(ServeRuntime):
         """Vanilla tick: one scan-fused decode block, per-row bits."""
         pool = self.pool
         slots = self.slots
-        wv, av = self._batch_bits()
-        keys = self._split_key(self.decode_block)
-        tok = jnp.asarray(slots["tok"][:, None], jnp.int32)
-        t = jnp.asarray(slots["t"], jnp.int32)
-        temp = jnp.asarray(slots["temp"], jnp.float32)
-        topk = jnp.asarray(slots["topk"], jnp.int32)
-        decode = (self._decode_scan_sh if self._decode_scan_sh is not None
-                  else self._decode_scan)
-        tok, t, pool.cache, toks = decode(
-            self.qparams, tok, t, pool.cache, wv, av, temp, topk, keys)
+        with self.stats.span("decode.dispatch"):
+            wv, av = self._batch_bits()
+            keys = self._split_key(self.decode_block)
+            tok = jnp.asarray(slots["tok"][:, None], jnp.int32)
+            t = jnp.asarray(slots["t"], jnp.int32)
+            temp = jnp.asarray(slots["temp"], jnp.float32)
+            topk = jnp.asarray(slots["topk"], jnp.int32)
+            decode = (self._decode_scan_sh
+                      if self._decode_scan_sh is not None
+                      else self._decode_scan)
+            tok, t, pool.cache, toks = decode(
+                self.qparams, tok, t, pool.cache, wv, av, temp, topk, keys)
         # ONE coalesced device->host transfer per tick
-        tok_h, toks_h = jax.device_get((tok, toks))
-        slots["tok"][:] = tok_h[:, 0].astype(np.int64)
-        slots["t"][:] += self.decode_block
-        for slot in np.nonzero(active)[0]:
-            rid = int(slots.rid[slot])
-            st = self.requests[rid]
-            take = int(min(slots["remaining"][slot], self.decode_block))
-            new = toks_h[slot, :take].tolist()
-            if self.eos_id is not None and self.eos_id in new:
-                new = new[:new.index(self.eos_id) + 1]
-            st.tokens.extend(int(x) for x in new)
-            self.stats.tokens += len(new)
-            slots["remaining"][slot] -= take
-            hit_eos = (self.eos_id is not None and new
-                       and new[-1] == self.eos_id)
-            if slots["remaining"][slot] <= 0 or hit_eos:
-                self._finish(slot)
+        with self.stats.span("decode.sync"):
+            tok_h, toks_h = jax.device_get((tok, toks))
+        with self.stats.span("decode.harvest"):
+            slots["tok"][:] = tok_h[:, 0].astype(np.int64)
+            slots["t"][:] += self.decode_block
+            for slot in np.nonzero(active)[0]:
+                rid = int(slots.rid[slot])
+                st = self.requests[rid]
+                take = int(min(slots["remaining"][slot], self.decode_block))
+                new = toks_h[slot, :take].tolist()
+                if self.eos_id is not None and self.eos_id in new:
+                    new = new[:new.index(self.eos_id) + 1]
+                st.tokens.extend(int(x) for x in new)
+                self.stats.tokens += len(new)
+                slots["remaining"][slot] -= take
+                hit_eos = (self.eos_id is not None and new
+                           and new[-1] == self.eos_id)
+                if slots["remaining"][slot] <= 0 or hit_eos:
+                    self._finish(slot)
 
     def _spec_round(self, active, k_eff_h) -> None:
         """One speculative round for the whole batch: draft SPEC_K_MAX
@@ -888,57 +913,60 @@ class ServeEngine(ServeRuntime):
         vanilla path either way."""
         pool = self.pool
         slots = self.slots
-        wv, av = self._batch_bits()
-        dwv, dav = self._draft_bits()
-        keys = self._split_key(SPEC_K_MAX + 2)
-        tok = jnp.asarray(slots["tok"][:, None], jnp.int32)
-        t = jnp.asarray(slots["t"], jnp.int32)
-        temp = jnp.asarray(slots["temp"], jnp.float32)
-        topk = jnp.asarray(slots["topk"], jnp.int32)
-        k_eff = jnp.asarray(k_eff_h, jnp.int32)
-        draft_toks, draft_probs, pool.cache = self._draft(
-            self.qparams, tok, t, pool.cache, dwv, dav, temp, topk,
-            keys[:SPEC_K_MAX])
-        nxt, t_next, emitted, count, keep, pool.cache = self._verify(
-            self.qparams, tok, draft_toks, draft_probs, t, pool.cache,
-            wv, av, k_eff, temp, topk, keys[SPEC_K_MAX],
-            keys[SPEC_K_MAX + 1])
-        pool.rollback(keep)
+        with self.stats.span("decode.dispatch"):
+            wv, av = self._batch_bits()
+            dwv, dav = self._draft_bits()
+            keys = self._split_key(SPEC_K_MAX + 2)
+            tok = jnp.asarray(slots["tok"][:, None], jnp.int32)
+            t = jnp.asarray(slots["t"], jnp.int32)
+            temp = jnp.asarray(slots["temp"], jnp.float32)
+            topk = jnp.asarray(slots["topk"], jnp.int32)
+            k_eff = jnp.asarray(k_eff_h, jnp.int32)
+            draft_toks, draft_probs, pool.cache = self._draft(
+                self.qparams, tok, t, pool.cache, dwv, dav, temp, topk,
+                keys[:SPEC_K_MAX])
+            nxt, t_next, emitted, count, keep, pool.cache = self._verify(
+                self.qparams, tok, draft_toks, draft_probs, t, pool.cache,
+                wv, av, k_eff, temp, topk, keys[SPEC_K_MAX],
+                keys[SPEC_K_MAX + 1])
+            pool.rollback(keep)
         # ONE coalesced device->host transfer per round
-        nxt_h, t_next_h, emitted_h, count_h = jax.device_get(
-            (nxt, t_next, emitted, count))
-        slots["tok"][:] = nxt_h.astype(np.int64)
-        slots["t"][:] = t_next_h.astype(np.int64)
-        for slot in np.nonzero(active)[0]:
-            rid = int(slots.rid[slot])
-            st = self.requests[rid]
-            take = int(count_h[slot])           # a + 1 <= remaining
-            new = emitted_h[slot, :take].tolist()
-            if self.eos_id is not None and self.eos_id in new:
-                new = new[:new.index(self.eos_id) + 1]
-            st.tokens.extend(int(x) for x in new)
-            self.stats.tokens += len(new)
-            slots["remaining"][slot] -= take
-            k_req = int(slots["k"][slot])
-            if k_req > 0:
-                # honest per-round actuals at the REQUEST's chosen depth
-                # (clamped tail rounds still run/charge the full-width
-                # chunk; acceptance just can't use the tail)
-                st.spec_rounds += 1
-                st.draft_units += k_req
-                st.verify_units += k_req + 1
-                st.accepted_units += take - 1
-                st.spec_tokens += len(new)
-                st.draft_wbits = self._draft_wbits_f
-                if isinstance(self.controller, FluidController):
-                    # close the draft-bit loop: this round's accept rate
-                    # (accepted drafts over drafted) feeds the EMA that
-                    # may shift the NEXT round's draft config
-                    self.controller.observe_accept((take - 1) / k_req)
-            hit_eos = (self.eos_id is not None and new
-                       and new[-1] == self.eos_id)
-            if slots["remaining"][slot] <= 0 or hit_eos:
-                self._finish(slot)
+        with self.stats.span("decode.sync"):
+            nxt_h, t_next_h, emitted_h, count_h = jax.device_get(
+                (nxt, t_next, emitted, count))
+        with self.stats.span("decode.harvest"):
+            slots["tok"][:] = nxt_h.astype(np.int64)
+            slots["t"][:] = t_next_h.astype(np.int64)
+            for slot in np.nonzero(active)[0]:
+                rid = int(slots.rid[slot])
+                st = self.requests[rid]
+                take = int(count_h[slot])           # a + 1 <= remaining
+                new = emitted_h[slot, :take].tolist()
+                if self.eos_id is not None and self.eos_id in new:
+                    new = new[:new.index(self.eos_id) + 1]
+                st.tokens.extend(int(x) for x in new)
+                self.stats.tokens += len(new)
+                slots["remaining"][slot] -= take
+                k_req = int(slots["k"][slot])
+                if k_req > 0:
+                    # honest per-round actuals at the REQUEST's chosen depth
+                    # (clamped tail rounds still run/charge the full-width
+                    # chunk; acceptance just can't use the tail)
+                    st.spec_rounds += 1
+                    st.draft_units += k_req
+                    st.verify_units += k_req + 1
+                    st.accepted_units += take - 1
+                    st.spec_tokens += len(new)
+                    st.draft_wbits = self._draft_wbits_f
+                    if isinstance(self.controller, FluidController):
+                        # close the draft-bit loop: this round's accept rate
+                        # (accepted drafts over drafted) feeds the EMA that
+                        # may shift the NEXT round's draft config
+                        self.controller.observe_accept((take - 1) / k_req)
+                hit_eos = (self.eos_id is not None and new
+                           and new[-1] == self.eos_id)
+                if slots["remaining"][slot] <= 0 or hit_eos:
+                    self._finish(slot)
 
 
 def _default_policy() -> PrecisionPolicy:

@@ -147,12 +147,21 @@ class ServeRuntime:
         self._config_costs: Optional[List[apm.BitVectorCost]] = None
         self._lats_np: Optional[np.ndarray] = None
         self._tabs_np: Optional[Tuple[np.ndarray, np.ndarray]] = None
-        # scheduler clock + deferred (timestamped) arrivals: submit_at()
-        # registers a submit thunk for a future tick; run() drains the
-        # due thunks at the top of each tick (trace replay enqueues by
-        # timestamp, never all-up-front)
-        self._tick = 0
+        # deferred (timestamped) arrivals: submit_at() registers a submit
+        # thunk for a future tick; run() drains the due thunks at the top
+        # of each tick (trace replay enqueues by timestamp, never
+        # all-up-front)
         self._arrivals: Dict[int, List[Callable[[], int]]] = {}
+
+    @property
+    def _tick(self) -> int:
+        """The scheduler clock.  It lives on ``stats`` (``clock``), so
+        every span and mark is stamped with this tick."""
+        return self.stats.clock
+
+    @_tick.setter
+    def _tick(self, t: int) -> None:
+        self.stats.clock = t
 
     # ------------------------------------------------------------------
     # Pricing / control loop
@@ -436,13 +445,14 @@ class ServeRuntime:
 
     def sched_tick(self) -> List[int]:
         """One instrumented scheduler tick: advance tick-windowed fluid
-        controllers, run the adapter's :meth:`step`, record queue depth,
-        and advance the scheduler clock.  Returns the rids that finished
-        during the tick."""
-        if isinstance(self.controller, FluidController):
-            self.controller.tick()
-        done = self.step()
-        self.stats.record_tick(self.queued, self._active_count())
+        controllers, run the adapter's :meth:`step` and record queue
+        depth, inside the ``tick`` span; then advance the scheduler
+        clock.  Returns the rids that finished during the tick."""
+        with self.stats.span("tick"):
+            if isinstance(self.controller, FluidController):
+                self.controller.tick()
+            done = self.step()
+            self.stats.record_tick(self.queued, self._active_count())
         self._tick += 1
         return done
 
