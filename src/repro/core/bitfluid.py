@@ -16,6 +16,12 @@ no hardware reconfiguration.  We map that insight onto TPU as follows
   configuration an ordinary *runtime tensor* — one compiled program serves
   any static or dynamic mixed-precision configuration (the TPU analogue of
   "no reconfiguration overhead at run-time").
+* **Container invariant**: every stored container holds values on the
+  *symmetric* grid ``|q| <= 2^(b-1) - 1`` of its width ``b`` (``quantize``
+  clips there; ``-2^(b-1)`` never occurs).  So ``requant_shift`` at
+  ``to_bits >= from_bits`` (shift 0) is the identity, and the serve kernel
+  skips it, sending the tile to the MXU as stored.  Anything that makes a
+  container must keep this.
 * Training uses fake-quant with a straight-through estimator so the same
   per-layer bit vector drives quantization-aware training.
 

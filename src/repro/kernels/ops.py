@@ -20,15 +20,18 @@ prove the TPU path (``chip_smoke.py``) can refuse them.
 Per-precision specializations are cached by (n_planes, block shape) via
 jit's static-arg cache: switching a layer between 2/4/8 bits after warmup
 costs no recompilation — the dispatch-cache realization of bit fluidity.
+The serve GEMM's blocks are a function of its shape and plane count
+(``_bitplane_tiles``): each weight is one kernel pass, with no XLA op
+reading it first.
 
 Bit-grouped batch execution
 ---------------------------
 Per-request precision hands ``serve_linear`` a ``(B,)`` bit vector.  The
 naive realization (one weight requantization per row) does O(B·K·N) weight
 work for at most a handful of distinct bit-widths.  Instead, the grouped
-path requantizes the container once per *family* in the static
-``BIT_FAMILIES`` set, runs one batch GEMM per family (each at a static
-plane count — the plane-serial kernel's cost ∝ bits), and gathers each
+path runs one batch GEMM per *family* in the static ``BIT_FAMILIES`` set
+(each at a static plane count — the plane-serial kernel's cost ∝ bits —
+requantizing the container on its VMEM tiles), and gathers each
 row's result from its family's accumulator: O(G·K·N) weight work,
 zero-retrace (family membership is data).  ``set_bit_families`` /
 ``bit_families`` narrow the set to the precisions a serving policy can
@@ -44,7 +47,7 @@ from __future__ import annotations
 
 import contextlib
 import os
-from typing import Optional, Sequence
+from typing import NamedTuple, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -53,6 +56,7 @@ import numpy as np
 from repro.core import bitfluid as bf
 from repro.kernels import ref as kref
 from repro.kernels.bitplane_matmul import bitplane_matmul as _bitplane_pallas
+from repro.kernels.bitplane_matmul import vmem_bytes as _bitplane_vmem
 from repro.kernels.quant_matmul import quant_matmul as _quant_pallas
 from repro.kernels.int4_matmul import int4_matmul as _int4_pallas
 
@@ -204,21 +208,82 @@ def _blocks_for(M: int, N: int, K: int):
 
 
 # ---------------------------------------------------------------------------
+# Serve GEMM tiles: sized by shape, so each weight is one kernel pass.
+# ---------------------------------------------------------------------------
+
+_BM_MAX = 512             # rows per block: a prefill row reads each weight once
+_BN_MAX = 512             # lane-dense column block; N >= 1024 keeps 2+ blocks
+_W_TILE_BYTES = 4 << 20   # int8 weight tile moved per grid step
+_VMEM_BUDGET = 32 << 20   # the kernel's working set (v5e VMEM: 128 MiB)
+
+
+class Tiles(NamedTuple):
+    bm: int
+    bn: int
+    bk: int
+    mp: int               # padded dims: multiples of the blocks
+    np: int
+    kp: int
+
+
+def _round_up(d: int, m: int) -> int:
+    return -(-d // m) * m
+
+
+def _lane_blocks(d: int, cap: Optional[int]):
+    """(padded d, candidate blocks, largest first): multiples of 128 that
+    divide d rounded up to 128, so an aligned dim is never padded (and no
+    XLA op copies a weight before the kernel); a dim under 128 is one
+    block, as ``_block_dim`` sizes it."""
+    if d < 128:
+        b = _block_dim(d)
+        return b, [b]
+    lanes = _round_up(d, 128) // 128
+    return lanes * 128, [128 * m for m in range(lanes, 0, -1)
+                         if lanes % m == 0 and (cap is None or 128 * m <= cap)]
+
+
+def _bitplane_tiles(M: int, N: int, K: int, n_planes: int) -> Tiles:
+    """Blocks for the serve GEMM kernel, a function of its shape alone.
+
+    bm is the whole of M (rounded up to the int8 sublane tile of 32) up
+    to 512; bk is the whole of K wherever the tile fits; bn is the widest
+    lane-dense divisor of N up to 512 whose int8 tile stays within 4 MiB.
+    Every pair must keep the working set (``vmem_bytes``, which counts the
+    plane walk's scratch at ``n_planes < 8``) within the budget; bk shrinks
+    only when no bn fits.  qwen3-4b's decode layer: 65 grid steps."""
+    nb = -(-M // _BM_MAX)
+    bm = _round_up(-(-M // nb), 32)
+    np_, bns = _lane_blocks(N, _BN_MAX)
+    kp, bks = _lane_blocks(K, None)
+    for bk in bks:
+        for bn in bns:
+            if (bk * bn <= _W_TILE_BYTES and
+                    _bitplane_vmem(bm, bn, bk, n_planes) <= _VMEM_BUDGET):
+                return Tiles(bm, bn, bk, nb * bm, np_, kp)
+    return Tiles(bm, bns[-1], bks[-1], nb * bm, np_, kp)
+
+
+def _bitplane(x_q, w, to_bits, *, n_planes, from_bits, interpret):
+    M, K = x_q.shape
+    N = w.shape[1]
+    t = _bitplane_tiles(M, N, K, n_planes)
+    xp = _pad_to(x_q, (t.mp, t.kp))
+    wp = _pad_to(w, (t.kp, t.np))
+    out = _bitplane_pallas(xp, wp, to_bits, from_bits, n_planes=n_planes,
+                           bm=t.bm, bn=t.bn, bk=t.bk, interpret=interpret)
+    return out[:M, :N]
+
 
 def bitplane_matmul(x_q: jnp.ndarray, w_q: jnp.ndarray, *, n_planes: int = 8,
                     interpret: bool = False) -> jnp.ndarray:
-    """int8 (M,K) @ int8-container (K,N) -> int32 (M,N), plane-serial."""
+    """int8 (M,K) @ int8-container (K,N) -> int32 (M,N), plane-serial over
+    the container as stored (no requantization)."""
     interpret = _interp(interpret)
     if not (use_pallas() or interpret):
         return kref.bitplane_matmul_ref(x_q, w_q, n_planes)
-    M, K = x_q.shape
-    N = w_q.shape[1]
-    bm, bn, bk = _blocks_for(M, N, K)
-    xp = _pad_to(x_q, (bm, bk))
-    wp = _pad_to(w_q, (bk, bn))
-    out = _bitplane_pallas(xp, wp, n_planes=n_planes, bm=bm, bn=bn, bk=bk,
-                           interpret=interpret)
-    return out[:M, :N]
+    return _bitplane(x_q, w_q, 8, n_planes=n_planes, from_bits=8,
+                     interpret=interpret)
 
 
 def quant_matmul(x_q: jnp.ndarray, w_q: jnp.ndarray, scale: jnp.ndarray,
@@ -292,17 +357,25 @@ def _static_bits(b) -> Optional[int]:
     return None
 
 
-def int8_accum(x_q: jnp.ndarray, w_q: jnp.ndarray, *,
-               planes: Optional[int] = None,
-               interpret: bool = False) -> jnp.ndarray:
-    """int8 (M,K) @ int8 (K,N) -> int32 through the kernel layer.
+def int8_accum(x_q: jnp.ndarray, w: jnp.ndarray, to_bits, *,
+               from_bits: int = 8, interpret: bool = False) -> jnp.ndarray:
+    """int8 (M,K) @ a ``from_bits`` container (K,N) requantized to
+    ``to_bits`` -> int32 (M,N), through the kernel layer.
 
-    Static ``planes`` runs the plane-serial kernel at exactly that many
-    bit planes (TPU cost ∝ assigned bits); None means the bits were traced
-    upstream, so the container-width path runs (the 8-plane walk lowers to
-    one native int8 MXU matmul)."""
-    n = 8 if planes is None else min(max(planes, 1), 8)
-    return bitplane_matmul(x_q, w_q, n_planes=n, interpret=interpret)
+    Static ``to_bits`` runs the plane-serial kernel at exactly that many
+    bit planes (TPU cost ∝ assigned bits); traced bits run the
+    container-width path (the 8-plane walk lowers to one native int8 MXU
+    matmul).  The kernel takes the container as stored and requantizes
+    each tile in VMEM only at a positive shift, so no XLA op reads the
+    weight first; the ref path requantizes in XLA."""
+    tb = _static_bits(to_bits)
+    n = 8 if tb is None else min(max(tb, 1), 8)
+    interpret = _interp(interpret)
+    if not (use_pallas() or interpret):
+        return kref.bitplane_matmul_ref(
+            x_q, bf.requant_shift(w, to_bits, from_bits=from_bits), n)
+    return _bitplane(x_q, w, to_bits, n_planes=n, from_bits=from_bits,
+                     interpret=interpret)
 
 
 def _epilogue(acc2, lead, x_scale, w_s, bias):
@@ -318,10 +391,9 @@ def _container_linear(x, qw, s, bias, *, from_bits, wbits, abits, interpret):
     x2 = x.astype(jnp.float32)
     x_scale = bf.symmetric_scale(x2, abits)           # per-tensor scalar
     x_q = bf.quantize(x2, x_scale, abits)
-    w_q = bf.requant_shift(qw, wbits, from_bits=from_bits)
     w_s = bf.effective_scale(s, wbits, from_bits=from_bits)
-    acc = int8_accum(x_q.reshape(-1, x.shape[-1]), w_q,
-                     planes=_static_bits(wbits), interpret=interpret)
+    acc = int8_accum(x_q.reshape(-1, x.shape[-1]), qw, wbits,
+                     from_bits=from_bits, interpret=interpret)
     return _epilogue(acc, x.shape[:-1], x_scale, w_s, bias)
 
 
@@ -457,15 +529,16 @@ def _serve_linear_rows(p, x, wbits, abits, interpret):
     xq2 = x_q.reshape(-1, K)                                # (R, K)
     R = xq2.shape[0]
 
-    # one requant + one grouped GEMM per distinct family — families below
-    # the container width collapse (requant 4->6 == 4->4 for a q4 container)
+    # one grouped GEMM (requantizing on the tile) per distinct family —
+    # families above the container width collapse (requant 4->6 == 4->4
+    # for a q4 container)
     fams = tuple(_families)
     eff = [min(f, from_bits) for f in fams]
     uniq = sorted(set(eff))
     accs, scales = [], []
     for f in uniq:
-        w_f = bf.requant_shift(qw, f, from_bits=from_bits)
-        accs.append(int8_accum(xq2, w_f, planes=f, interpret=interpret))
+        accs.append(int8_accum(xq2, qw, f, from_bits=from_bits,
+                               interpret=interpret))
         scales.append(jnp.broadcast_to(
             jnp.asarray(bf.effective_scale(p["s"], f, from_bits=from_bits),
                         jnp.float32).reshape(1, -1), (1, accs[-1].shape[-1])))

@@ -164,7 +164,7 @@ def test_grouped_dispatch_zero_retrace(rng):
 
 
 # ---------------------------------------------------------------------------
-# Satellite: _blocks_for + int4 alignment behavior
+# Satellite: _blocks_for, the serve GEMM's tiles, int4 alignment behavior
 # ---------------------------------------------------------------------------
 
 def test_blocks_for_shrinks_all_dims():
@@ -172,6 +172,68 @@ def test_blocks_for_shrinks_all_dims():
     assert ops._blocks_for(64, 32, 16) == (64, 32, 16)
     assert ops._blocks_for(1, 2, 3) == (8, 8, 8)        # floor at 8
     assert ops._blocks_for(100, 72, 200) == (128, 128, 128)  # next pow2 >= 128
+
+
+# qwen3-4b's seven serve linears, (K, N)
+QWEN3_4B_LINEARS = {"q": (2560, 4096), "k": (2560, 1024), "v": (2560, 1024),
+                    "o": (4096, 2560), "gate": (2560, 9728),
+                    "up": (2560, 9728), "down": (9728, 2560)}
+V5E_VMEM = 128 << 20
+
+
+@pytest.mark.parametrize("n_planes", [8, 4])
+@pytest.mark.parametrize("m", [48, 512], ids=["decode", "prefill"])
+def test_bitplane_tiles_qwen3_4b(m, n_planes):
+    """The serve GEMM's tiles at qwen3-4b's shapes: blocks divide the
+    padded dims (aligned weights unpadded), the working set — the plane
+    walk's int8 planes and int32 temporaries included — stays inside the
+    VMEM limit the kernel is compiled with, and a layer is a few dozen
+    grid steps (6,160 at the 128-capped blocks)."""
+    from repro.kernels.bitplane_matmul import vmem_bytes, vmem_limit_bytes
+    steps = 0
+    for K, N in QWEN3_4B_LINEARS.values():
+        t = ops._bitplane_tiles(m, N, K, n_planes)
+        assert t.mp % t.bm == 0 and t.np % t.bn == 0 and t.kp % t.bk == 0
+        assert t.mp >= m and (t.kp, t.np) == (K, N)
+        assert t.bm <= 512 and t.bm % 32 == 0 and t.bn % 128 == 0
+        ws = vmem_bytes(t.bm, t.bn, t.bk, n_planes)
+        planes = (n_planes + 1) * t.bk * t.bn if n_planes < 8 else 0
+        assert planes < ws <= ops._VMEM_BUDGET
+        assert ws <= vmem_limit_bytes(t.bm, t.bn, t.bk, n_planes) <= V5E_VMEM
+        steps += (t.mp // t.bm) * (t.np // t.bn) * (t.kp // t.bk)
+    assert steps <= 100
+
+
+@pytest.mark.parametrize("shape", [(48, 256, 19 * 128), (512, 384, 256),
+                                   (64, 300, 640)],
+                         ids=["odd_n_blocks", "m512", "k_padded"])
+@pytest.mark.parametrize("container", ["int8", "q4"])
+@pytest.mark.parametrize("traced", [False, True], ids=["static", "traced"])
+@pytest.mark.parametrize("to_bits", ops.BIT_FAMILIES)
+def test_serve_gemm_requant_on_tile_exact(rng, shape, container, traced,
+                                          to_bits):
+    """The kernel at its chosen tiles, requantizing on the tile, is
+    bit-identical to ``requant_shift`` then the plane-walk oracle, for
+    every family, static and traced bits, int8 and q4 containers."""
+    M, K, N = shape
+    from_bits = 8 if container == "int8" else 4
+    lim = 2 ** (from_bits - 1) - 1                 # symmetric container grid
+    x = jnp.asarray(rng.integers(-127, 128, (M, K)).astype(np.int8))
+    w = jnp.asarray(rng.integers(-lim, lim + 1, (K, N)).astype(np.int8))
+    if container == "q4":
+        w = bf.unpack_int4_halves(bf.pack_int4_halves(w))
+    if traced:
+        got = jax.jit(lambda a, b, t: ops.int8_accum(
+            a, b, t, from_bits=from_bits, interpret=True))(
+                x, w, jnp.asarray(to_bits, jnp.int32))
+    else:
+        got = ops.int8_accum(x, w, to_bits, from_bits=from_bits,
+                             interpret=True)
+    w_req = bf.requant_shift(w, to_bits, from_bits=from_bits)
+    want = ref.bitplane_matmul_ref(x, w_req, 8 if traced else to_bits)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    exact = np.asarray(x, np.int64) @ np.asarray(w_req, np.int64)
+    np.testing.assert_array_equal(np.asarray(got), exact)
 
 
 def test_int4_matmul_unaligned_falls_back(rng):

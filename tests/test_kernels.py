@@ -5,6 +5,7 @@ import pytest
 
 from repro.core import bitfluid as bf
 from repro.kernels import ops, ref
+from repro.models import lm
 
 
 @pytest.mark.parametrize("bits", [1, 2, 4, 8])
@@ -32,6 +33,33 @@ def test_bitplane_matmul_nonaligned(rng):
                               n_planes=8, interpret=True)
     np.testing.assert_array_equal(
         np.asarray(out), x.astype(np.int64) @ w.astype(np.int64))
+
+
+@pytest.mark.parametrize("maker,bits", [("quantize", 8), ("quantize", 4),
+                                        ("quantize", 2),
+                                        ("quantize_weight", 8),
+                                        ("quantize_weight", 4),
+                                        ("int4_halves", 4)])
+def test_containers_on_symmetric_grid(rng, maker, bits):
+    """Every container maker stays on the symmetric grid |q| <= 2^(b-1)-1
+    (the container invariant), even where values clip: requantizing at
+    shift 0 is then the identity, which the serve kernel relies on."""
+    w = jnp.asarray((rng.standard_t(2, size=(96, 64)) * 0.1
+                     ).astype(np.float32))
+    if maker == "quantize":                  # half the scale: many clip
+        q = bf.quantize(w, bf.symmetric_scale(w, bits, axis=0) / 2, bits)
+    else:
+        q, _ = lm._quantize_weight(w, bits)
+    if maker == "int4_halves":
+        q4 = bf.unpack_int4_halves(bf.pack_int4_halves(q))
+        np.testing.assert_array_equal(np.asarray(q4), np.asarray(q))
+        q = q4
+    lim = 2 ** (bits - 1) - 1
+    qn = np.asarray(q, np.int32)
+    assert qn.max() <= lim and qn.min() >= -lim
+    assert np.abs(qn).max() == lim           # the grid's edge is reached
+    np.testing.assert_array_equal(
+        np.asarray(bf.requant_shift(q, bits, from_bits=bits)), qn)
 
 
 @pytest.mark.parametrize("act", ["none", "relu", "silu", "gelu"])
