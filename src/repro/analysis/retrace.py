@@ -249,7 +249,11 @@ def _audit_engine(name: str, cfg) -> List[TraceReport]:
          for temp in (0.0, 0.7)],
         eng._sample_first))
 
-    # ---- extend_row: partial prefix-cache hits -----------------------
+    # ---- extend_row: partial prefix-cache hits (refused for families
+    # whose rows hold a recurrent state: no prefix cache, no extension)
+    if cfg.family in lm.RECURRENT_STATE_FAMILIES:
+        return reports
+
     def extend_fn(qp, budget, tokens, row, start, r):
         wv, av = eng.controller.resolve(budget)
         return eng._extend_row(qp, tokens, row, start, r, wv, av)

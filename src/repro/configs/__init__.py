@@ -22,6 +22,7 @@ ARCH_IDS = (
     "moonshot_v1_16b_a3b",
     "zamba2_2_7b",
     "seamless_m4t_medium",
+    "granite_4_0_h_micro",
 )
 
 _ALIASES = {i.replace("_", "-"): i for i in ARCH_IDS}
@@ -29,6 +30,7 @@ _ALIASES.update({
     "qwen1.5-110b": "qwen1_5_110b",
     "mamba2-1.3b": "mamba2_1_3b",
     "zamba2-2.7b": "zamba2_2_7b",
+    "granite-4.0-h-micro": "granite_4_0_h_micro",
 })
 
 

@@ -59,6 +59,7 @@ from repro.kernels.bitplane_matmul import bitplane_matmul as _bitplane_pallas
 from repro.kernels.bitplane_matmul import vmem_bytes as _bitplane_vmem
 from repro.kernels.quant_matmul import quant_matmul as _quant_pallas
 from repro.kernels.int4_matmul import int4_matmul as _int4_pallas
+from repro.kernels.ssm_update import ssm_update as _ssm_update_pallas
 
 INTERPRET_ENV = os.environ.get("REPRO_PALLAS", "").lower() == "interpret"
 _FORCE: Optional[bool] = True if INTERPRET_ENV else None
@@ -607,3 +608,29 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
     out = _fa(qp, kp, vp, causal=causal, window=window, k_len=Sk,
               scale=hd ** -0.5, bq=bq, bk=bk, interpret=interpret)
     return out[:, :Sq, :hd]
+
+
+def ssm_update(state: jnp.ndarray, layer, x: jnp.ndarray, dt: jnp.ndarray,
+               dA: jnp.ndarray, b: jnp.ndarray, c: jnp.ndarray, live, *,
+               interpret: bool = False):
+    """One decode step of a Mamba-2 layer's state, in place at ``layer``
+    of the stacked state (L, B, H, P, N) f32: x (B, H, P), dt and
+    dA = exp(a dt) (B, H), b and c (B, N), live (B,).  Live rows get
+    h = dA h + (dt x) B and y = h . C; empty rows keep their state and
+    give y = 0.  Returns (y (B, H, P) f32, state).
+
+    On the TPU the Pallas ``ssm_update`` kernel reads each live slot's
+    block once and writes it back into the same buffer; elsewhere the
+    jnp oracle (a dynamic-update-slice XLA also does in place)."""
+    interpret = _interp(interpret)
+    f32 = jnp.float32
+    x, dt, dA = x.astype(f32), dt.astype(f32), dA.astype(f32)
+    b, c = b.astype(f32), c.astype(f32)
+    if not (use_pallas() or interpret):
+        return kref.ssm_update_ref(state, layer, x, dt, dA, b, c, live)
+    B, H, P = x.shape
+    u_t = jnp.swapaxes(dt[..., None] * x, 1, 2)              # (B, P, H)
+    a_t = jnp.broadcast_to(dA[:, None, :], (B, P, H))
+    y_t, state = _ssm_update_pallas(state, layer, u_t, a_t, b[:, None],
+                                    c[:, None], live, interpret=interpret)
+    return jnp.swapaxes(y_t, 1, 2), state

@@ -155,3 +155,19 @@ def flash_attention_chunked_ref(q, k, v, causal: bool = True,
     _, blocks = jax.lax.scan(q_block, None, (q5, qpos))   # (nq, BH, Qc, hd)
     out = jnp.moveaxis(blocks, 0, 1).reshape(BH, nq * Qc, hd)
     return out[:, :Sq].astype(q.dtype)
+
+
+def ssm_update_ref(state, layer, x, dt, dA, b, c, live):
+    """Oracle of ``ssm_update``: one decode step of layer ``layer`` of a
+    stacked Mamba-2 state (L, B, H, P, N) f32.  x (B, H, P), dt and dA
+    (B, H), b and c (B, N), live (B,).  Live rows: h = dA h + (dt x) B,
+    y = h . C; empty rows keep their state and give y = 0.  Returns
+    (y (B, H, P), state)."""
+    s = jax.lax.dynamic_index_in_dim(state, layer, 0, keepdims=False)
+    new = (s * dA[:, :, None, None]
+           + (dt[:, :, None] * x)[..., None] * b[:, None, None, :])
+    y = jnp.sum(new * c[:, None, None, :], axis=-1)
+    on = jnp.asarray(live).astype(bool)
+    new = jnp.where(on[:, None, None, None], new, s)
+    y = jnp.where(on[:, None, None], y, 0.0)
+    return y, jax.lax.dynamic_update_index_in_dim(state, new, layer, 0)

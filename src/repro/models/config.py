@@ -8,7 +8,8 @@ from typing import Tuple
 @dataclasses.dataclass(frozen=True)
 class ModelConfig:
     name: str
-    family: str                  # dense | moe | ssm | hybrid | encdec | vlm
+    family: str                  # dense | moe | ssm | hybrid | mamba_attn
+    #                              | encdec | vlm
     n_layers: int
     d_model: int
     vocab_size: int
@@ -36,9 +37,20 @@ class ModelConfig:
     expand: int = 2
     d_conv: int = 4
     ssm_chunk: int = 128
+    ssm_groups: int = 1          # B/C groups (1: shared by every head)
+    conv_bias: bool = True
+    # layer-pattern hybrid (mamba_attn): one period of "M" (Mamba-2 mixer)
+    # and "A" (GQA attention) letters, repeated n_layers / len times;
+    # every layer has its own MLP
+    layer_pattern: str = ""
     # hybrid (zamba2): one shared attn+mlp block every `attn_every` layers
     attn_every: int = 0
     lora_rank: int = 0
+    # muP multipliers (granite): the defaults leave a model unscaled
+    attention_multiplier: float = 0.0    # softmax scale; 0 -> head_dim**-0.5
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0     # each sublayer's output
+    logits_scaling: float = 1.0          # logits divided by this
     # enc-dec (seamless)
     n_enc_layers: int = 0
     frames_ratio: int = 4        # decoder tokens per encoder frame (stub)

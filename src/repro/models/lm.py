@@ -33,7 +33,8 @@ from repro import dist
 from repro.core import bitfluid as bf
 from repro.kernels import ops as kops
 from repro.models import common as cm
-from repro.models import encdec, hybrid, mamba2, moe, transformer as tf
+from repro.models import (encdec, hybrid, mamba2, mamba_attn, moe,
+                          transformer as tf)
 from repro.models.config import ModelConfig
 
 MOE_AUX_COEF = 0.01
@@ -41,10 +42,14 @@ MOE_AUX_COEF = 0.01
 # Families whose layer stacks accept (B, n_layers) per-request bit
 # matrices.  MoE resolves a *per-expert* axis instead (DESIGN.md §4);
 # hybrid shares one attention block batch-wide; encdec shares the encoder.
-PER_ROW_BIT_FAMILIES = ("dense", "vlm", "ssm")
+PER_ROW_BIT_FAMILIES = ("dense", "vlm", "ssm", "mamba_attn")
 # Families whose prefill supports ragged per-row prompt lengths (attention
-# masks padding out; SSM recurrences would consume the pad tokens).
-RAGGED_PREFILL_FAMILIES = ("dense", "vlm")
+# masks padding out; mamba_attn masks its state updates past each row's
+# length; the plain ssm/hybrid recurrences would consume the pad tokens).
+RAGGED_PREFILL_FAMILIES = ("dense", "vlm", "mamba_attn")
+# Families whose cache holds a recurrent state per row: it cannot be cut
+# back to a prefix, so prefix-cache installs and rollbacks are refused.
+RECURRENT_STATE_FAMILIES = ("mamba_attn",)
 
 
 # ---------------------------------------------------------------------------
@@ -92,6 +97,10 @@ def layer_gemm_dims(cfg: ModelConfig):
     mam = ((d, 2 * d_inner + 2 * N + H), (d_inner, d))    # in/out proj
     if cfg.family == "ssm":
         return (mam,) * cfg.n_layers
+    if cfg.family == "mamba_attn":
+        per = {"M": mam + mlp(cfg.d_ff), "A": attn + mlp(cfg.d_ff)}
+        return tuple(per[k] for k in cfg.layer_pattern) * (
+            cfg.n_layers // len(cfg.layer_pattern))
     if cfg.family == "hybrid":
         per = attn + mlp(cfg.d_ff) + mam * cfg.attn_every
         return (per,) * hybrid.n_super(cfg)
@@ -129,6 +138,8 @@ def init_params(cfg: ModelConfig, key) -> dict:
             jax.random.split(k_layers, cfg.n_layers))
     elif cfg.family == "hybrid":
         p["layers"] = hybrid.hybrid_init(k_layers, cfg)
+    elif cfg.family == "mamba_attn":
+        p["layers"] = mamba_attn.init(k_layers, cfg)
     elif cfg.family == "encdec":
         p["layers"] = encdec.encdec_init(k_layers, cfg)
     else:
@@ -282,8 +293,10 @@ def _layer_major(vec, family: str):
 
 
 def forward_hidden(params, x, cfg: ModelConfig, wvec, avec, *, positions,
-                   cache=None, t=None, enc_out=None):
-    """Embedded inputs -> final hidden states.  Returns (h, cache, aux)."""
+                   cache=None, t=None, enc_out=None, valid=None):
+    """Embedded inputs -> final hidden states.  Returns (h, cache, aux).
+    ``valid`` (B, S): real tokens of a right-padded prefill (mamba_attn
+    masks its state updates with it)."""
     fam = cfg.family
     wvec = _layer_major(wvec, fam)
     avec = _layer_major(avec, fam)
@@ -295,6 +308,11 @@ def forward_hidden(params, x, cfg: ModelConfig, wvec, avec, *, positions,
                             cache, t, mlp_fn=moe.apply_moe)
     if fam == "ssm":
         return _ssm_stack(params["layers"], x, cfg, wvec, avec, cache)
+    if fam == "mamba_attn":
+        h, new_cache = mamba_attn.forward(
+            params["layers"], x, cfg, wvec, avec, positions=positions,
+            cache=cache, t=t, valid=valid)
+        return h, new_cache, jnp.zeros((), jnp.float32)
     if fam == "hybrid":
         h, new_cache = hybrid.hybrid_forward(
             params["layers"], x, cfg, wvec, avec, positions=positions,
@@ -332,6 +350,8 @@ def logits_fn(params, h: jnp.ndarray, cfg: ModelConfig, wb=8, ab=8):
     else:
         logits = cm.apply_linear(params["head"], h, wb, ab
                                  ).astype(jnp.float32)
+    if cfg.logits_scaling != 1.0:
+        logits = logits / cfg.logits_scaling
     if cfg.padded_vocab != cfg.vocab_size:       # mask padding ids
         pad_mask = jnp.arange(cfg.padded_vocab) >= cfg.vocab_size
         logits = jnp.where(pad_mask, -1e30, logits)
@@ -391,6 +411,8 @@ def empty_cache(cfg: ModelConfig, batch: int, max_len: int) -> dict:
         return mamba2.empty_state(cfg, batch, cfg.n_layers)
     if cfg.family == "hybrid":
         return hybrid.empty_hybrid_cache(cfg, batch, max_len)
+    if cfg.family == "mamba_attn":
+        return mamba_attn.empty_cache(cfg, batch, max_len)
     if cfg.family == "encdec":
         frames = max(max_len // cfg.frames_ratio, 1)
         return {
@@ -431,6 +453,7 @@ def prefill(params, batch: dict, cfg: ModelConfig, wvec, avec, cache: dict,
                                 cfg, wvec, avec)
         cache = {"self": cache["self"]}        # cross is rebuilt from enc_out
     Sx = x.shape[1]
+    valid = None
     if lengths is None:
         # (1, Sx): rows share positions, so attention keeps its shared
         # (S, S) mask instead of materializing a (B, S, S) batched one
@@ -451,9 +474,10 @@ def prefill(params, batch: dict, cfg: ModelConfig, wvec, avec, cache: dict,
         # zero pad embeddings so per-row dynamic activation scales see only
         # real tokens (keeps ragged rows numerically close to standalone)
         x = jnp.where(valid[..., None], x, 0).astype(x.dtype)
-    h, new_cache, _ = forward_hidden(params, x, cfg, wvec, avec,
-                                     positions=positions, cache=cache,
-                                     enc_out=enc_out)
+    h, new_cache, _ = forward_hidden(
+        params, x, cfg, wvec, avec, positions=positions, cache=cache,
+        enc_out=enc_out,
+        valid=valid if cfg.family in RECURRENT_STATE_FAMILIES else None)
     if lengths is None:
         h_last = h[:, -1:]
     else:
@@ -574,10 +598,14 @@ class CachePool:
             return jax.tree.map(leaf, pool)
 
         def reset_row(pool, slot):
+            # kpos -> EMPTY_POS masks the KV ring; a recurrent row's
+            # state and conv window are zeroed and its live flag cleared
             def leaf(p, path):
-                if path and path[-1] == "kpos":
-                    empty = jnp.full((p.shape[0], 1) + p.shape[2:],
-                                     tf.EMPTY_POS, p.dtype)
+                fill = {"kpos": tf.EMPTY_POS, "live": 0, "ssm": 0,
+                        "conv": 0}.get(path[-1] if path else "")
+                if fill is not None:
+                    empty = jnp.full((p.shape[0], 1) + p.shape[2:], fill,
+                                     p.dtype)
                     return jax.lax.dynamic_update_slice(
                         p, empty, (0, slot) + (0,) * (p.ndim - 2))
                 return p
@@ -642,6 +670,13 @@ class CachePool:
             raise ValueError(f"row length {length} not in "
                              f"[0, max_len={self.max_len}]")
 
+    def _refuse_recurrent(self, what: str) -> None:
+        if self.cfg.family in RECURRENT_STATE_FAMILIES:
+            raise NotImplementedError(
+                f"{what} cuts a row back to fewer tokens; family "
+                f"{self.cfg.family!r} keeps a recurrent state per row, "
+                f"which holds every token it has seen")
+
     def write_row(self, row_cache, slot: int, length: int) -> None:
         """Install a prefilled single-row cache into ``slot``."""
         self._check_install(slot, length)
@@ -654,6 +689,7 @@ class CachePool:
         cache into ``slot`` (the prefix-cache hit path): positions
         >= ``keep`` are masked EMPTY on the way in, and the source row
         is copied, never donated — the cache tier keeps its entry."""
+        self._refuse_recurrent("a prefix install")
         self._check_install(slot, keep)
         self.lengths[slot] = keep
         self.cache = self._install(self.cache, row_cache,
@@ -667,6 +703,7 @@ class CachePool:
         int32 vector of last-kept absolute positions; slots not in a
         speculative round pass any value >= EMPTY_POS (no-op).  Runs as
         one jitted donate-in-place masking — no retrace across rounds."""
+        self._refuse_recurrent("a rollback")
         self.cache = self._rollback(self.cache,
                                     jnp.asarray(keeps, jnp.int32))
 

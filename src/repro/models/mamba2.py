@@ -66,14 +66,17 @@ def _split(p, xz, cfg):
 
 
 def _causal_conv(w, b, xBC):
-    """Depthwise causal conv, window K, via K shifted adds. xBC: (B,S,C)."""
+    """Depthwise causal conv, window K, via K shifted adds. xBC: (B,S,C);
+    ``b`` may be None (no conv bias)."""
     K = w.shape[0]
     pad = jnp.pad(xBC, ((0, 0), (K - 1, 0), (0, 0)))
     S = xBC.shape[1]
     y = jnp.zeros_like(xBC, dtype=jnp.float32)
     for i in range(K):
         y = y + pad[:, i:i + S].astype(jnp.float32) * w[i].astype(jnp.float32)
-    return jax.nn.silu(y + b.astype(jnp.float32)).astype(xBC.dtype)
+    if b is not None:
+        y = y + b.astype(jnp.float32)
+    return jax.nn.silu(y).astype(xBC.dtype)
 
 
 def ssd_chunked(xh, Bm, Cm, dt, a, h0, chunk: int):
@@ -121,6 +124,40 @@ def ssd_chunked(xh, Bm, Cm, dt, a, h0, chunk: int):
     return y, h_final
 
 
+def mixer_in(p, h, cfg, wbits=8, abits=8):
+    """in_proj of the normed input, split: (z, xBC, dt_raw)."""
+    return _split(p, cm.apply_linear(p["in_proj"], h, wbits, abits), cfg)
+
+
+def split_xbc(xBC, cfg):
+    """Conv output (..., S, C) -> x heads (..., S, H, P), B, C (..., S, N)."""
+    d_inner, H, N, P = dims(cfg)
+    xh = xBC[..., :d_inner].reshape(*xBC.shape[:-1], H, P)
+    return xh, xBC[..., d_inner:d_inner + N], xBC[..., d_inner + N:]
+
+
+def conv_step(p, conv_state, xBC):
+    """One decode step of the causal conv: window (B, K-1, C) + the new
+    input (B, 1, C) -> (activated output (B, 1, C) f32, next window)."""
+    conv_in = jnp.concatenate([conv_state, xBC], axis=1)       # (B,K,C)
+    y = jnp.einsum("bkc,kc->bc", conv_in.astype(jnp.float32),
+                   p["conv_w"].astype(jnp.float32))
+    if "conv_b" in p:
+        y = y + p["conv_b"].astype(jnp.float32)
+    return jax.nn.silu(y)[:, None], conv_in[:, 1:]
+
+
+def mixer_out(p, y, xh, z, cfg, wbits=8, abits=8):
+    """D skip, gate, grouped RMSNorm and out_proj of the SSM output
+    y (B, S, H, P) f32."""
+    d_inner = dims(cfg)[0]
+    y = y + p["D"][None, None, :, None] * xh.astype(jnp.float32)
+    y = y.reshape(*y.shape[:2], d_inner)
+    y = y * jax.nn.silu(z.astype(jnp.float32))
+    y = cm.rms_norm(y.astype(cm.DTYPE), p["gn"]["scale"], cfg.norm_eps)
+    return cm.apply_linear(p["out_proj"], y, wbits, abits)
+
+
 def mamba_block(p, x, cfg, wbits=8, abits=8, *, state: Optional[dict] = None
                 ) -> Tuple[jnp.ndarray, Optional[dict]]:
     """x: (B, S, d).
@@ -131,50 +168,32 @@ def mamba_block(p, x, cfg, wbits=8, abits=8, *, state: Optional[dict] = None
     """
     d_inner, H, N, P = dims(cfg)
     res = x
-    xz = cm.apply_linear(p["in_proj"],
-                         cm.rms_norm(x, p["ln"]["scale"], cfg.norm_eps),
-                         wbits, abits)
-    z, xBC, dt_raw = _split(p, xz, cfg)
+    z, xBC, dt_raw = mixer_in(p, cm.rms_norm(x, p["ln"]["scale"],
+                                             cfg.norm_eps), cfg, wbits, abits)
     a = -jnp.exp(p["A_log"])                                # (H,)
+    dt = jax.nn.softplus(dt_raw.astype(jnp.float32)
+                         + p["dt_bias"][None, None])        # (B,S,H)
 
     if state is None or x.shape[1] > 1:
-        xBC_raw = xBC
-        xBC = _causal_conv(p["conv_w"], p["conv_b"], xBC)
-        xh = xBC[..., :d_inner].reshape(*x.shape[:2], H, P)
-        Bm = xBC[..., d_inner:d_inner + N]
-        Cm = xBC[..., d_inner + N:]
-        dt = jax.nn.softplus(dt_raw.astype(jnp.float32)
-                             + p["dt_bias"][None, None])
+        xh, Bm, Cm = split_xbc(
+            _causal_conv(p["conv_w"], p["conv_b"], xBC), cfg)
         h0 = (state["ssm"] if state is not None
               else jnp.zeros((x.shape[0], H, P, N), jnp.float32))
         y, h_fin = ssd_chunked(xh, Bm, Cm, dt, a, h0, cfg.ssm_chunk)
         new_state = None
         if state is not None:
             K = cfg.d_conv
-            new_state = {"conv": xBC_raw[:, x.shape[1] - (K - 1):, :],
+            new_state = {"conv": xBC[:, x.shape[1] - (K - 1):, :],
                          "ssm": h_fin}
     else:
         # decode: roll conv window, single SSM step
-        conv_in = jnp.concatenate([state["conv"], xBC], axis=1)  # (B,K,C)
-        xBC1 = jax.nn.silu(
-            jnp.einsum("bkc,kc->bc", conv_in.astype(jnp.float32),
-                       p["conv_w"].astype(jnp.float32))
-            + p["conv_b"].astype(jnp.float32))[:, None]          # (B,1,C)
-        xh = xBC1[..., :d_inner].reshape(x.shape[0], 1, H, P)
-        Bm = xBC1[..., d_inner:d_inner + N]
-        Cm = xBC1[..., d_inner + N:]
-        dt = jax.nn.softplus(dt_raw.astype(jnp.float32)
-                             + p["dt_bias"][None, None])         # (B,1,H)
+        xBC1, conv = conv_step(p, state["conv"], xBC)
+        xh, Bm, Cm = split_xbc(xBC1, cfg)
         dA = jnp.exp(a[None, :] * dt[:, 0])                      # (B,H)
         h = state["ssm"] * dA[..., None, None] + jnp.einsum(
             "bh,bn,bhp->bhpn", dt[:, 0], Bm[:, 0].astype(jnp.float32),
             xh[:, 0].astype(jnp.float32))
         y = jnp.einsum("bn,bhpn->bhp", Cm[:, 0].astype(jnp.float32), h)[:, None]
-        new_state = {"conv": conv_in[:, 1:], "ssm": h}
+        new_state = {"conv": conv, "ssm": h}
 
-    y = y + p["D"][None, None, :, None] * xh.astype(jnp.float32)
-    y = y.reshape(*x.shape[:2], d_inner)
-    y = y * jax.nn.silu(z.astype(jnp.float32))
-    y = cm.rms_norm(y.astype(cm.DTYPE), p["gn"]["scale"], cfg.norm_eps)
-    out = cm.apply_linear(p["out_proj"], y, wbits, abits)
-    return res + out, new_state
+    return res + mixer_out(p, y, xh, z, cfg, wbits, abits), new_state

@@ -115,6 +115,12 @@ def _row_insert(buf: jnp.ndarray, new: jnp.ndarray, slot: jnp.ndarray
         b, n, (s,) + (0,) * (b.ndim - 1)))(buf, new, slot)
 
 
+def _softmax_scale(cfg, hd: int) -> float:
+    """The scores' scale: ``attention_multiplier`` where the config
+    states one (granite's muP), else 1/sqrt(head_dim)."""
+    return cfg.attention_multiplier or hd ** -0.5
+
+
 def _sdpa_int8(q, kq, ks, vq, vs, bias, cfg):
     """Decode attention on the int8 cache: scores = (q_q . k_q) sq ks.
 
@@ -129,7 +135,7 @@ def _sdpa_int8(q, kq, ks, vq, vs, bias, cfg):
     qs_g = qs.reshape(B, Sq, KV, G).transpose(0, 2, 3, 1)[..., None]
     scores = (acc.astype(jnp.float32) * qs_g.astype(jnp.float32)
               * ks.astype(jnp.float32).transpose(0, 2, 1)[:, :, None, None, :])
-    scores = scores * (hd ** -0.5) + bias[:, None, None]
+    scores = scores * _softmax_scale(cfg, hd) + bias[:, None, None]
     probs = jax.nn.softmax(scores, axis=-1)
     # fold v scales into probs, quantize probs to int8 (p in [0,1])
     pv = probs * vs.astype(jnp.float32).transpose(0, 2, 1)[:, :, None, None, :]
@@ -169,7 +175,7 @@ def _sdpa(q, k, v, bias, cfg):
     qg = q.reshape(B, Sq, KV, G, hd)
     scores = jnp.einsum("bqkgd,bskd->bkgqs", qg, k,
                         preferred_element_type=jnp.float32)
-    scores = scores * (hd ** -0.5)
+    scores = scores * _softmax_scale(cfg, hd)
     if bias.ndim == 2:
         scores = scores + bias[None, None, None]
     else:
@@ -198,6 +204,8 @@ def _flash(q, k, v, cfg, causal: bool):
     B, Sq, H, hd = q.shape
     Sk, KV = k.shape[1], k.shape[2]
     G = H // KV
+    if cfg.attention_multiplier:              # the kernel scales by hd**-.5
+        q = q * (cfg.attention_multiplier * hd ** 0.5)
     if G > 1:                                 # expand GQA to flat heads
         k = jnp.repeat(k, G, axis=2)
         v = jnp.repeat(v, G, axis=2)
@@ -214,12 +222,16 @@ def _flash(q, k, v, cfg, causal: bool):
 
 def attention(p, x, cfg, wbits=8, abits=8, *, positions, causal: bool = True,
               kv: Optional[Tuple[jnp.ndarray, jnp.ndarray]] = None,
-              cache: Optional[dict] = None, t=None):
+              cache: Optional[dict] = None, t=None,
+              valid: Optional[jnp.ndarray] = None):
     """Self- or cross-attention with optional cache update.
 
     positions: (B, S) absolute positions of x's tokens (for RoPE + mask).
     kv:        precomputed (k, v) for cross-attention (RoPE skipped).
     cache/t:   decode path — insert this step's k/v at slot t % Sc.
+    valid:     (B, S) real tokens of a right-padded batch; padded
+               positions give the output projection a zero input, so
+               its per-request activation scale sees real tokens only.
     Returns (out, new_cache).
     """
     q, k_new, v_new = _qkv(p, x, cfg, wbits, abits)
@@ -324,6 +336,8 @@ def attention(p, x, cfg, wbits=8, abits=8, *, positions, causal: bool = True,
         if cache is not None:                            # prefill: fill cache
             new_cache = prefill_cache_insert(cache, k_new, v_new, positions)
 
+    if valid is not None:
+        out = jnp.where(valid[..., None], out, 0).astype(out.dtype)
     y = cm.apply_linear(p["wo"], out, wbits, abits)
     return y, new_cache
 

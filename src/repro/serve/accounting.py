@@ -112,6 +112,9 @@ class RuntimeStats:
         # that request prefilled or extended; a full prefix-cache hit
         # prefills none, a partial one only the tokens past its prefix
         self.prefill_by_rid: Dict[int, Tuple[int, int]] = {}
+        # LM, recurrent-state families: tick -> rows whose state that
+        # tick's decode block carried (live slots; the rest are skipped)
+        self.state_rows: Dict[int, int] = {}
         self.events: collections.deque = collections.deque(maxlen=SPAN_RING)
         self._seq = 0                   # events opened so far
         self._open: List[int] = []      # seqs of the spans now open

@@ -33,6 +33,7 @@ benchmark baseline in benchmarks/serve_throughput.py).
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import time
 from typing import Dict, List, Optional
@@ -146,6 +147,15 @@ class ServeEngine(ServeRuntime):
                     f"speculative decoding needs the chunked verify path; "
                     f"family {cfg.family!r} is unsupported "
                     f"(supported: {lm.SPEC_CHUNK_FAMILIES})")
+        # a recurrent state holds every token a row has seen: it cannot be
+        # cut back to a cached prefix (DESIGN.md §10 needs that cut)
+        self._recurrent = cfg.family in lm.RECURRENT_STATE_FAMILIES
+        if self._recurrent and prefix_cache is not None:
+            raise ValueError(
+                f"the prefix cache installs a row cut back to a shared "
+                f"prefix; family {cfg.family!r} keeps a recurrent state "
+                f"per row, which cannot be cut — serve it with "
+                f"prefix_cache=None")
         self.spec_k = spec_k
         self._draft_budget_f = (0.0 if draft_budget_s is None
                                 else float(draft_budget_s))
@@ -777,7 +787,9 @@ class ServeEngine(ServeRuntime):
                     jnp.asarray([S], jnp.int32), wv, av, *extra)
                 prefix_len = (self.cfg.n_prefix_tokens
                               if self.cfg.family == "vlm" else 0)
-                pool.write_row(row_cache, slot, S + prefix_len)
+                with (self.stats.span("prefill.state_install")
+                      if self._recurrent else contextlib.nullcontext()):
+                    pool.write_row(row_cache, slot, S + prefix_len)
                 self.stats.prefill_by_rid[req.rid] = (
                     S + prefix_len, self.prefill_len + prefix_len)
                 if wv_np is not None:   # cacheable miss: store/refresh
@@ -868,6 +880,8 @@ class ServeEngine(ServeRuntime):
         """Vanilla tick: one scan-fused decode block, per-row bits."""
         pool = self.pool
         slots = self.slots
+        if self._recurrent:         # rows whose state the block carries
+            self.stats.state_rows[self._tick] = int(active.sum())
         with self.stats.span("decode.dispatch"):
             wv, av = self._batch_bits()
             keys = self._split_key(self.decode_block)

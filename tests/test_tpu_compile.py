@@ -29,6 +29,7 @@ from repro.kernels.bitplane_matmul import bitplane_matmul
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.int4_matmul import int4_matmul
 from repro.kernels.quant_matmul import quant_matmul
+from repro.kernels.ssm_update import ssm_update
 
 D_MODEL, D_FF = 2560, 9728          # qwen3-4b MLP projection (K, N)
 # qwen3-4b's serve linears by (K, N): q, k and v, o, gate and up, down
@@ -102,6 +103,25 @@ def test_flash_attention_compiles(one_chip):
     fn = functools.partial(flash_attention, causal=True, scale=hd ** -0.5)
     qkv = [_spec((8, s, hd), jnp.bfloat16, one_chip) for _ in range(3)]
     _assert_kernel(fn, *qkv)
+
+
+def test_ssm_update_compiles(one_chip):
+    """The decode state update at granite-4.0-h-micro's shapes, 48 slots:
+    state (36, 48, 64, 64, 128) f32, one slot's (64, 64, 128) block a grid
+    step; one Mosaic call whose state output is its state operand."""
+    L, B, H, P, N = 36, 48, 64, 64, 128
+    text = jax.jit(ssm_update, donate_argnums=(0,)).lower(
+        _spec((L, B, H, P, N), jnp.float32, one_chip),
+        _spec((), jnp.int32, one_chip),
+        _spec((B, P, H), jnp.float32, one_chip),
+        _spec((B, P, H), jnp.float32, one_chip),
+        _spec((B, 1, N), jnp.float32, one_chip),
+        _spec((B, 1, N), jnp.float32, one_chip),
+        _spec((B,), jnp.int32, one_chip)).compile().as_text()
+    call = [ln for ln in text.splitlines()
+            if 'custom_call_target="tpu_custom_call"' in ln]
+    assert len(call) == 1 and "output_to_operand_aliasing={{1}: (8, {})}" \
+        in call[0]
 
 
 def _weight_producers(hlo: str):
