@@ -202,14 +202,14 @@ def _axis_entry(axes: Tuple[str, ...]):
 
 
 def _kv_cache_spec(mesh, shape) -> P:
-    """(L, B, S, KV, hd) cache spec.
+    """(L, B, KV, S, hd) cache spec.
 
     dp goes on the batch dim when it divides; a B=1 long-context decode
     shards the SEQUENCE over dp instead (the ring buffer is per-slot, so
     sequence sharding is legal).  tp goes on KV heads when they divide,
     else on the per-head feature dim (small-GQA models).
     """
-    L, B, S, KV, hd = shape
+    L, B, KV, S, hd = shape
     entries: list = [None] * 5
     dp_axes = api.mesh_axes_for(mesh, "dp")
     dp_sz = api.dp_size(mesh)
@@ -217,12 +217,12 @@ def _kv_cache_spec(mesh, shape) -> P:
         if B % dp_sz == 0:
             entries[1] = _axis_entry(dp_axes)
         elif S % dp_sz == 0:
-            entries[2] = _axis_entry(dp_axes)
+            entries[3] = _axis_entry(dp_axes)
     tp_axes = api.mesh_axes_for(mesh, "tp")
     tp_sz = api.tp_size(mesh)
     if tp_sz > 1:
         if KV % tp_sz == 0:
-            entries[3] = _axis_entry(tp_axes)
+            entries[2] = _axis_entry(tp_axes)
         elif hd % tp_sz == 0:
             entries[4] = _axis_entry(tp_axes)
     return P(*entries)
@@ -240,7 +240,7 @@ def _cache_leaf_spec(mesh, keys: Tuple[str, ...], leaf) -> P:
             return P(None, _axis_entry(api.mesh_axes_for(mesh, "dp")), None)
         return P(None, None, None)
     if name in ("ks", "vs") and leaf.ndim == 4:     # int8 cache scales:
-        full = _kv_cache_spec(mesh, shape + (1,))   # (L, B, S, KV) = k/v
+        full = _kv_cache_spec(mesh, shape + (1,))   # (L, B, KV, S) = k/v
         return P(*tuple(full)[:4])                  # minus the head dim
     if name == "ssm" and leaf.ndim >= 3:            # (L, B, H, P, N)
         return logical_to_mesh(

@@ -67,11 +67,13 @@ def cross_kv(p_dec, enc_out: jnp.ndarray, cfg, wvec, avec) -> dict:
 
 
 def decoder_block(p, x, cfg, wb, ab, *, positions, enc_kv,
-                  cache: Optional[dict] = None, t=None):
-    """Self-attn + cross-attn + MLP.  enc_kv: (k, v) for this layer."""
+                  cache: Optional[dict] = None, layer=None, t=None):
+    """Self-attn + cross-attn + MLP.  enc_kv: (k, v) for this layer;
+    ``cache`` the stacked self-attention cache, ``layer`` its index."""
     h, new_cache = tf.attention(
         p["attn"], cm.apply_norm(p["ln1"], x, cfg.norm_type, cfg.norm_eps),
-        cfg, wb, ab, positions=positions, causal=True, cache=cache, t=t)
+        cfg, wb, ab, positions=positions, causal=True, cache=cache,
+        layer=layer, t=t)
     x = x + h
     hx, _ = tf.attention(
         p["xattn"], cm.apply_norm(p["lnx"], x, cfg.norm_type, cfg.norm_eps),
@@ -86,23 +88,16 @@ def decoder_forward(p, x, cfg, wvec, avec, *, positions,
                     enc_kv: dict, cache: Optional[dict] = None, t=None
                     ) -> Tuple[jnp.ndarray, Optional[dict]]:
     """x: (B, S, d) decoder-side embeddings; enc_kv stacked (L, ...)."""
-    def body(carry, scanned):
-        x = carry
-        if cache is not None:
-            lp, wb, ab, ek, ev, cl = scanned
-        else:
-            lp, wb, ab, ek, ev = scanned
-            cl = None
-        x, new_cl = decoder_block(lp, x, cfg, wb, ab, positions=positions,
-                                  enc_kv=(ek, ev), cache=cl, t=t)
-        return x, (new_cl if cache is not None else ())
+    def body(x, cache, l, scanned):
+        lp, wb, ab, ek, ev = scanned
+        x, cache = decoder_block(lp, x, cfg, wb, ab, positions=positions,
+                                 enc_kv=(ek, ev), cache=cache, layer=l, t=t)
+        return x, cache, ()
 
     n_dec = cfg.n_layers
     wd, ad = wvec[-n_dec:], avec[-n_dec:]
     xs = (p["dec"], wd, ad, enc_kv["k"], enc_kv["v"])
-    if cache is not None:
-        xs = xs + (cache,)
-    body = (jax.checkpoint(body) if cfg.remat == "full" and cache is None
-            else body)
-    x, ys = jax.lax.scan(body, x, xs)
-    return x, (ys if cache is not None else None)
+    if cfg.remat == "full" and cache is None:
+        body = jax.checkpoint(body)
+    x, cache, _ = tf.cached_layer_scan(body, x, xs, cache)
+    return x, cache

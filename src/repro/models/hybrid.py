@@ -82,19 +82,18 @@ def hybrid_forward(p, x, cfg, wbits, abits, *, positions,
     shared = p["shared"]
     decode = cache is not None
 
-    def super_block(carry, scanned):
-        x = carry
+    def super_block(x, kv, i, scanned):
         if decode:
-            (mp, lora, wb_i, ab_i, kv_c, m_c) = scanned
+            (mp, lora, wb_i, ab_i, m_c) = scanned
         else:
             (mp, lora, wb_i, ab_i) = scanned
-            kv_c, m_c = None, None
+            m_c = None
         attn_p = {"ln1": shared["ln1"], "ln2": shared["ln2"],
                   "mlp": shared["mlp"],
                   "attn": _lora_attn_params(shared["attn"], lora)}
-        x, new_kv, _ = transformer.block(
+        x, kv, _ = transformer.block(
             attn_p, x, cfg, wb[0], ab[0], positions=positions,
-            cache=kv_c, t=t)
+            cache=kv, layer=i, t=t)
 
         def mamba_layer(xc, inner):
             if decode:
@@ -109,21 +108,21 @@ def hybrid_forward(p, x, cfg, wbits, abits, *, positions,
 
         inner_xs = (mp, m_c["conv"], m_c["ssm"]) if decode else (mp,)
         x, m_out = jax.lax.scan(mamba_layer, x, inner_xs)
-        ys = ((new_kv, {"conv": m_out[0], "ssm": m_out[1]}) if decode else ())
-        return x, ys
+        ys = {"conv": m_out[0], "ssm": m_out[1]} if decode else ()
+        return x, kv, ys
 
     if decode:
         ssm = jax.tree.map(
             lambda a: a.reshape(ns, cfg.attn_every, *a.shape[1:]),
             {"conv": cache["conv"], "ssm": cache["ssm"]})
-        xs = (p["mamba"], p["lora"], wb, ab, cache["kv"], ssm)
+        xs = (p["mamba"], p["lora"], wb, ab, ssm)
     else:
         xs = (p["mamba"], p["lora"], wb, ab)
     body = jax.checkpoint(super_block) if cfg.remat == "full" else super_block
-    x, ys = jax.lax.scan(body, x, xs)
+    x, kv_new, m_new = transformer.cached_layer_scan(
+        body, x, xs, cache["kv"] if decode else None)
     new_cache = None
     if decode:
-        kv_new, m_new = ys
         new_cache = {
             "kv": kv_new,
             "conv": m_new["conv"].reshape(cfg.n_layers, *m_new["conv"].shape[2:]),

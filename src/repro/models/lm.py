@@ -232,26 +232,16 @@ def init_serve_params(cfg: ModelConfig, key, container: str = "int8"
 
 def _dense_stack(layers, x, cfg, wvec, avec, positions, cache=None, t=None,
                  mlp_fn=None):
-    def body(carry, scanned):
-        x = carry
-        if cache is not None:
-            lp, wb, ab, cl = scanned
-        else:
-            lp, wb, ab = scanned
-            cl = None
-        x, new_cl, aux = tf.block(lp, x, cfg, wb, ab, positions=positions,
-                                  cache=cl, t=t, mlp_fn=mlp_fn)
-        x = dist.constrain(x, ("dp", None, None))
-        return x, ((new_cl, aux) if cache is not None else aux)
+    def body(x, cache, l, scanned):
+        lp, wb, ab = scanned
+        x, cache, aux = tf.block(lp, x, cfg, wb, ab, positions=positions,
+                                 cache=cache, layer=l, t=t, mlp_fn=mlp_fn)
+        return dist.constrain(x, ("dp", None, None)), cache, aux
 
     if cfg.remat == "full" and cache is None:
         body = jax.checkpoint(body)
-    xs = (layers, wvec, avec) + ((cache,) if cache is not None else ())
-    x, ys = jax.lax.scan(body, x, xs)
-    if cache is not None:
-        new_cache, aux = ys
-        return x, new_cache, jnp.mean(aux)
-    return x, None, jnp.mean(ys)
+    x, cache, aux = tf.cached_layer_scan(body, x, (layers, wvec, avec), cache)
+    return x, cache, jnp.mean(aux)
 
 
 def _ssm_stack(layers, x, cfg, wvec, avec, cache=None):

@@ -23,8 +23,9 @@ stay floating point (DESIGN.md §4).
 
 Cache: ``{"kv": transformer cache (n_attn, B, ...), "ssm": (n_mamba, B,
 H, P, N) f32, "conv": (n_mamba, B, K-1, C), "live": (1, B) int32}``.
-The state stacks ride the period scan as carries: decode updates layer
-l's block in place (``kops.ssm_update``), prefill writes it with a
+The whole cache rides the period scan as its carry: decode updates
+mixer l's state block in place (``kops.ssm_update``) and writes one KV
+column a row into attention layer l's ring; prefill writes both with a
 dynamic-update-slice.  ``live`` marks the rows holding a request
 (prefill sets it; ``lm.CachePool`` clears it on reset): the decode
 kernel leaves every other row's state untouched.
@@ -158,13 +159,14 @@ def _mlp(p, x, cfg, wb, ab):
     return _residual(x, tf.mlp(p["mlp"], h, cfg, wb, ab), cfg)
 
 
-def _attn(p, x, cfg, wb, ab, positions, kv, t, valid):
+def _attn(p, x, cfg, wb, ab, positions, st, layer, t, valid):
     h, kv = tf.attention(p["attn"],
                          cm.rms_norm(x, p["ln1"]["scale"], cfg.norm_eps),
-                         cfg, wb, ab, positions=positions, cache=kv, t=t,
-                         valid=valid)
+                         cfg, wb, ab, positions=positions,
+                         cache=None if st is None else st["kv"], layer=layer,
+                         t=t, valid=valid)
     x = _residual(x, h, cfg)
-    return _mlp(p, x, cfg, wb, ab), kv
+    return _mlp(p, x, cfg, wb, ab), (None if st is None else dict(st, kv=kv))
 
 
 def forward(p, x, cfg, wvec, avec, *, positions, cache: Optional[dict] = None,
@@ -175,7 +177,8 @@ def forward(p, x, cfg, wvec, avec, *, positions, cache: Optional[dict] = None,
     full sequence, no state (training).  With a cache, a decode step of
     one token a row when ``t`` (its positions) is given, else a prefill
     from the cache's state; ``valid`` (B, S) marks a right-padded
-    prefill's real tokens."""
+    prefill's real tokens.  The whole cache (KV ring and state) rides the
+    period scan's carry (``tf.cached_layer_scan``)."""
     pat, n_per, nm, na = layout(cfg)
     decode = cache is not None and t is not None
     x = (x.astype(jnp.float32) * cfg.embedding_multiplier).astype(x.dtype)
@@ -184,44 +187,31 @@ def forward(p, x, cfg, wvec, avec, *, positions, cache: Optional[dict] = None,
         return jax.tree.map(lambda a: a.reshape(n_per, k, *a.shape[1:]),
                             tree)
 
-    xs = {"i": jnp.arange(n_per, dtype=jnp.int32),
-          "mamba": periods(p["mamba"], nm), "attn": periods(p["attn"], na),
+    xs = {"mamba": periods(p["mamba"], nm), "attn": periods(p["attn"], na),
           "wb": periods(jnp.asarray(wvec), len(pat)),
           "ab": periods(jnp.asarray(avec), len(pat))}
-    st = None
-    if cache is not None:
-        xs["kv"] = periods(cache["kv"], na)
-        st = {"ssm": cache["ssm"], "conv": cache["conv"],
-              "live": cache["live"]}
 
-    def period(carry, sc):
-        x, st = carry
-        kv_out = []
+    def period(x, st, i, sc):
         mi = ai = 0
         for j, kind in enumerate(pat):
             wb, ab = sc["wb"][j], sc["ab"][j]
             if kind == "M":
                 lp = jax.tree.map(lambda a, m=mi: a[m], sc["mamba"])
-                x, st = _mamba(lp, x, cfg, wb, ab, st, sc["i"] * nm + mi,
-                               valid, decode)
+                x, st = _mamba(lp, x, cfg, wb, ab, st, i * nm + mi, valid,
+                               decode)
                 mi += 1
             else:
                 lp = jax.tree.map(lambda a, m=ai: a[m], sc["attn"])
-                kv = (None if "kv" not in sc else
-                      jax.tree.map(lambda a, m=ai: a[m], sc["kv"]))
-                x, kv = _attn(lp, x, cfg, wb, ab, positions, kv, t, valid)
-                kv_out.append(kv)
+                x, st = _attn(lp, x, cfg, wb, ab, positions, st, i * na + ai,
+                              t, valid)
                 ai += 1
-        ys = (jax.tree.map(lambda *a: jnp.stack(a), *kv_out)
-              if "kv" in sc else ())
-        return (x, st), ys
+        return x, st, ()
 
     body = (jax.checkpoint(period) if cfg.remat == "full" and cache is None
             else period)
-    (x, st), kv = jax.lax.scan(body, (x, st), xs)
+    x, st, _ = tf.cached_layer_scan(body, x, xs, cache)
     if cache is None:
         return x, None
     live = (st["live"] if decode
             else jnp.ones_like(st["live"]))     # a prefilled row is live
-    kv = jax.tree.map(lambda a: a.reshape(n_per * na, *a.shape[2:]), kv)
-    return x, {"kv": kv, "ssm": st["ssm"], "conv": st["conv"], "live": live}
+    return x, dict(st, live=live)

@@ -6,8 +6,14 @@ Everything is a pure function over parameter pytrees.  All GEMMs go through
 bit-fluid mixed precision in both train (fake-quant STE) and serve (integer
 container) modes.
 
-Cache convention (per layer):
-  {"k": (B, Sc, KV, hd), "v": (B, Sc, KV, hd), "kpos": (B, Sc) int32}
+Cache convention (stacked over layers):
+  {"k": (L, B, KV, Sc, hd), "v": (L, B, KV, Sc, hd), "kpos": (L, B, Sc) int32}
+The whole stack rides the layer scan's carry (:func:`cached_layer_scan`):
+layer ``l``'s attention writes its own columns of it in place and its
+dots read the slab ``[l]`` straight from it, so no scan ever builds a
+second stack.  Heads sit before slots so that a slab is already in the
+layout the attention dots take (batch b, kv head; then slots; then hd):
+the compiler folds the layer slice into the dots instead of copying it.
 ``Sc`` is the cache capacity — ``min(max_len, window)`` for sliding-window
 models, so a 500k-token starcoder2 decode keeps a 4k ring buffer.  Slot
 ``t % Sc`` is overwritten at step t; ``kpos`` records, *per batch row*,
@@ -83,7 +89,7 @@ def empty_cache(cfg, batch: int, max_len: int, n_layers: Optional[int] = None,
     run on the int8 MXU path (2x peak)."""
     L = n_layers if n_layers is not None else cfg.n_layers
     Sc = min(max_len, cfg.sliding_window) if cfg.sliding_window else max_len
-    kv = (L, batch, Sc, cfg.n_kv_heads, cfg.head_dim)
+    kv = (L, batch, cfg.n_kv_heads, Sc, cfg.head_dim)
     out = {"kpos": jnp.full((L, batch, Sc), EMPTY_POS, jnp.int32)}
     if cfg.kv_cache_bits == 8:
         out.update({
@@ -106,13 +112,49 @@ def _quant_heads(x: jnp.ndarray):
     return q, s[..., 0].astype(cm.DTYPE)
 
 
-def _row_insert(buf: jnp.ndarray, new: jnp.ndarray, slot: jnp.ndarray
-                ) -> jnp.ndarray:
-    """Write ``new`` (B, 1, ...) into ``buf`` (B, Sc, ...) at per-row ring
-    slot ``slot`` (B,) — the continuous-batching cache insert, where each
-    row sits at its own decode position."""
-    return jax.vmap(lambda b, n, s: jax.lax.dynamic_update_slice(
-        b, n, (s,) + (0,) * (b.ndim - 1)))(buf, new, slot)
+def _write_columns(cache: dict, layer, slots: jnp.ndarray,
+                   pos: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray) -> dict:
+    """Write one step's k/v (B, U, KV, hd) and positions ``pos`` (B, U)
+    into layer ``layer`` of the stacked cache at ring slots ``slots``
+    (B, U): each row at its own slots, every other entry untouched.  A
+    scatter on the carried stack, which XLA updates in place."""
+    B, U = slots.shape
+    rows = jnp.arange(B, dtype=jnp.int32)[:, None, None]
+    heads = jnp.arange(k.shape[2], dtype=jnp.int32)[None, None]
+    new = {"k": k, "v": v}
+    if "ks" in cache:                                    # int8 cache
+        new["k"], new["ks"] = _quant_heads(k)
+        new["v"], new["vs"] = _quant_heads(v)
+    out = {"kpos": cache["kpos"].at[layer, rows[..., 0], slots].set(
+        pos, unique_indices=True)}
+    for name, val in new.items():           # (B, U, KV, ...) at (b, h, slot)
+        out[name] = cache[name].at[layer, rows, heads, slots[..., None]].set(
+            val.astype(cache[name].dtype), unique_indices=True)
+    return out
+
+
+def cached_layer_scan(body, x, xs, cache=None):
+    """Scan ``body(x, cache, i, sc) -> (x, cache, y)`` over the leading
+    axis of ``xs``; returns ``(x, cache, ys)``.
+
+    The cache stack travels whole in the carry, never in ``xs``/``ys``:
+    step ``i`` reads its layer's slab from it and writes back only what
+    that layer changes (one column a row in decode), so XLA keeps the one
+    buffer and updates it in place.  Scanning the cache as ``xs`` would
+    slice a copy of each slab in and stack a new cache out, every step.
+    ``i`` (int32) indexes the cache's layer axis; ``cache`` None (the
+    training path) carries nothing."""
+    n = jax.tree.leaves(xs)[0].shape[0]
+
+    def step(carry, scanned):
+        x, cache = carry
+        i, sc = scanned
+        x, cache, y = body(x, cache, i, sc)
+        return (x, cache), y
+
+    (x, cache), ys = jax.lax.scan(
+        step, (x, cache), (jnp.arange(n, dtype=jnp.int32), xs))
+    return x, cache, ys
 
 
 def _softmax_scale(cfg, hd: int) -> float:
@@ -124,25 +166,25 @@ def _softmax_scale(cfg, hd: int) -> float:
 def _sdpa_int8(q, kq, ks, vq, vs, bias, cfg):
     """Decode attention on the int8 cache: scores = (q_q . k_q) sq ks.
 
-    q: (B,1,H,hd) bf16; kq/vq: (B,Sc,KV,hd) int8; ks/vs: (B,Sc,KV)."""
+    q: (B,Sq,H,hd) bf16; kq/vq: (B,KV,Sc,hd) int8; ks/vs: (B,KV,Sc)."""
     B, Sq, H, hd = q.shape
-    KV = kq.shape[2]
+    KV = kq.shape[1]
     G = H // KV
     qq, qs = _quant_heads(q)
     qg = qq.reshape(B, Sq, KV, G, hd)
-    acc = jnp.einsum("bqkgd,bskd->bkgqs", qg, kq,
+    acc = jnp.einsum("bqkgd,bksd->bkgqs", qg, kq,
                      preferred_element_type=jnp.int32)
     qs_g = qs.reshape(B, Sq, KV, G).transpose(0, 2, 3, 1)[..., None]
     scores = (acc.astype(jnp.float32) * qs_g.astype(jnp.float32)
-              * ks.astype(jnp.float32).transpose(0, 2, 1)[:, :, None, None, :])
+              * ks.astype(jnp.float32)[:, :, None, None, :])
     scores = scores * _softmax_scale(cfg, hd) + bias[:, None, None]
     probs = jax.nn.softmax(scores, axis=-1)
     # fold v scales into probs, quantize probs to int8 (p in [0,1])
-    pv = probs * vs.astype(jnp.float32).transpose(0, 2, 1)[:, :, None, None, :]
+    pv = probs * vs.astype(jnp.float32)[:, :, None, None, :]
     pmax = jnp.max(pv, axis=-1, keepdims=True) + 1e-9
     p_q = jnp.clip(jnp.round(pv / pmax * 127.0), 0, 127).astype(jnp.int8)
-    out = jnp.einsum("bkgqs,bskd->bqkgd", p_q, vq,
-                     preferred_element_type=jnp.int32)
+    out = jnp.einsum("bkgqs,bksd->bkgqd", p_q, vq,
+                     preferred_element_type=jnp.int32).transpose(0, 3, 1, 2, 4)
     out = out.astype(jnp.float32) * (pmax.transpose(0, 3, 1, 2, 4) / 127.0)
     return out.reshape(B, Sq, H * hd).astype(cm.DTYPE)
 
@@ -163,17 +205,18 @@ def _qkv(p, x, cfg, wbits, abits):
     return q, k, v
 
 
-def _sdpa(q, k, v, bias, cfg):
-    """q: (B,Sq,H,hd); k,v: (B,Sk,KV,hd); bias: (Sq,Sk) or (B,Sq,Sk).
+def _sdpa(q, k, v, bias, cfg, kv="bskd"):
+    """q: (B,Sq,H,hd); k,v: (B,Sk,KV,hd), or (B,KV,Sk,hd) with
+    ``kv="bksd"`` (the cache ring's slab); bias: (Sq,Sk) or (B,Sq,Sk).
 
     Grouped-query einsum; used for decode (Sq==1) and short sequences,
     where the scores tensor is small.  Long sequences take _flash (the
     kernel-layer flash dispatcher)."""
     B, Sq, H, hd = q.shape
-    KV = k.shape[2]
+    KV = k.shape[kv.index("k")]
     G = H // KV
     qg = q.reshape(B, Sq, KV, G, hd)
-    scores = jnp.einsum("bqkgd,bskd->bkgqs", qg, k,
+    scores = jnp.einsum(f"bqkgd,{kv}->bkgqs", qg, k,
                         preferred_element_type=jnp.float32)
     scores = scores * _softmax_scale(cfg, hd)
     if bias.ndim == 2:
@@ -181,8 +224,15 @@ def _sdpa(q, k, v, bias, cfg):
     else:
         scores = scores + bias[:, None, None]
     probs = jax.nn.softmax(scores, axis=-1)
-    out = jnp.einsum("bkgqs,bskd->bqkgd", probs.astype(k.dtype), v,
-                     preferred_element_type=jnp.float32)
+    if kv == "bksd":
+        # the dot's own output order, then a transpose: the CPU backend
+        # has no bf16 dot that emits "bqkgd" from a "bksd" slab
+        out = jnp.einsum("bkgqs,bksd->bkgqd", probs.astype(k.dtype), v,
+                         preferred_element_type=jnp.float32
+                         ).transpose(0, 3, 1, 2, 4)
+    else:
+        out = jnp.einsum("bkgqs,bskd->bqkgd", probs.astype(k.dtype), v,
+                         preferred_element_type=jnp.float32)
     return out.reshape(B, Sq, H * hd).astype(cm.DTYPE)
 
 
@@ -222,17 +272,19 @@ def _flash(q, k, v, cfg, causal: bool):
 
 def attention(p, x, cfg, wbits=8, abits=8, *, positions, causal: bool = True,
               kv: Optional[Tuple[jnp.ndarray, jnp.ndarray]] = None,
-              cache: Optional[dict] = None, t=None,
+              cache: Optional[dict] = None, layer=None, t=None,
               valid: Optional[jnp.ndarray] = None):
     """Self- or cross-attention with optional cache update.
 
     positions: (B, S) absolute positions of x's tokens (for RoPE + mask).
     kv:        precomputed (k, v) for cross-attention (RoPE skipped).
-    cache/t:   decode path — insert this step's k/v at slot t % Sc.
+    cache:     the layer-stacked cache; ``layer`` (int32) picks this
+               layer's slab.  With ``t``: decode, S >= 1 positions a row
+               written at slots ``positions % Sc``; without: prefill.
     valid:     (B, S) real tokens of a right-padded batch; padded
                positions give the output projection a zero input, so
                its per-request activation scale sees real tokens only.
-    Returns (out, new_cache).
+    Returns (out, new_cache), new_cache the updated stack.
     """
     q, k_new, v_new = _qkv(p, x, cfg, wbits, abits)
     if cfg.rope_theta > 0 and kv is None:
@@ -248,73 +300,33 @@ def attention(p, x, cfg, wbits=8, abits=8, *, positions, causal: bool = True,
         else:
             bias = jnp.zeros((q.shape[1], k.shape[1]), jnp.float32)
             out = _sdpa(q, k, v, bias, cfg)
-    elif cache is not None and x.shape[1] == 1:          # decode (S == 1)
-        # consistent head/hd sharding across q, k/v inserts, and the cache:
-        # the KV head count decides the axis for *all* of q/k/v
+    elif cache is not None and t is not None:            # decode
+        # One token a row (S == 1), or U consecutive positions a row in
+        # ONE forward (the speculative verify).  Writes land in the ring
+        # slots sequential decode uses; each query sees exactly the
+        # kpos <= pos prefix, so a chunk is bit-identical to U
+        # single-token steps (the draft's stale entries past each query
+        # are masked, and rejected slots are rolled back to EMPTY_POS by
+        # the caller).  Consistent head/hd sharding across q, the k/v
+        # inserts and the cache: the KV head count decides the axis.
         use_head = k_new.shape[2] % dist.api.tp_size() == 0
         q = dist.constrain_heads(q, 2, 3, use_head)
         k_new = dist.constrain_heads(k_new, 2, 3, use_head)
         v_new = dist.constrain_heads(v_new, 2, 3, use_head)
-        B = x.shape[0]
-        Sc = cache["k"].shape[1]
-        t_b = jnp.broadcast_to(jnp.asarray(t, jnp.int32), (B,))
-        slot = (t_b % Sc).astype(jnp.int32)
-        kpos = jax.vmap(lambda kp, tv, sl: jax.lax.dynamic_update_slice(
-            kp, tv[None], (sl,)))(cache["kpos"], t_b, slot)
-        visible = kpos <= positions[:, -1:]              # (B, Sc)
-        if cfg.sliding_window:
-            visible &= kpos > positions[:, -1:] - cfg.sliding_window
-        bias = jnp.where(visible, 0.0, -jnp.inf)[:, None, :].astype(jnp.float32)
-        bias = bias.reshape(B, 1, Sc)                    # (B, Sq=1, Sc)
-        if "ks" in cache:                                # int8 cache path
-            kq_n, ks_n = _quant_heads(k_new)
-            vq_n, vs_n = _quant_heads(v_new)
-            k = _row_insert(cache["k"], kq_n, slot)
-            v = _row_insert(cache["v"], vq_n, slot)
-            ks = _row_insert(cache["ks"], ks_n, slot)
-            vs = _row_insert(cache["vs"], vs_n, slot)
-            new_cache = {"k": k, "v": v, "ks": ks, "vs": vs, "kpos": kpos}
-            out = _sdpa_int8(q, k, ks, v, vs, bias, cfg)
-        else:
-            k = _row_insert(cache["k"], k_new, slot)
-            v = _row_insert(cache["v"], v_new, slot)
-            new_cache = {"k": k, "v": v, "kpos": kpos}
-            out = _sdpa(q, k, v, bias, cfg)
-    elif cache is not None and t is not None:            # chunked decode
-        # Speculative verify: U consecutive token positions per row in ONE
-        # forward.  Writes land in the same ring slots sequential decode
-        # would use; each query position sees exactly the kpos <= pos
-        # prefix, so the chunk is bit-identical to U single-token steps
-        # (the draft's stale entries past each query are masked, and
-        # rejected slots are rolled back to EMPTY_POS by the caller).
-        use_head = k_new.shape[2] % dist.api.tp_size() == 0
-        q = dist.constrain_heads(q, 2, 3, use_head)
-        k_new = dist.constrain_heads(k_new, 2, 3, use_head)
-        v_new = dist.constrain_heads(v_new, 2, 3, use_head)
-        B, U = x.shape[:2]
-        Sc = cache["k"].shape[1]
         pos = positions.astype(jnp.int32)                # (B, U)
-        slots = (pos % Sc).astype(jnp.int32)             # (B, U)
-        scatter = jax.vmap(lambda b, n, s: b.at[s].set(n))
-        kpos = scatter(cache["kpos"], pos, slots)
-        visible = kpos[:, None, :] <= pos[:, :, None]    # (B, U, Sc)
+        new_cache = _write_columns(cache, layer, pos % cache["k"].shape[3],
+                                   pos, k_new, v_new)
+        c = {name: jax.lax.dynamic_index_in_dim(buf, layer, 0, False)
+             for name, buf in new_cache.items()}         # layer's slab
+        kpos = c["kpos"][:, None, :]
+        visible = kpos <= pos[:, :, None]                # (B, U, Sc)
         if cfg.sliding_window:
-            visible &= kpos[:, None, :] > pos[:, :, None] - cfg.sliding_window
+            visible &= kpos > pos[:, :, None] - cfg.sliding_window
         bias = jnp.where(visible, 0.0, -jnp.inf).astype(jnp.float32)
-        if "ks" in cache:                                # int8 cache path
-            kq_n, ks_n = _quant_heads(k_new)
-            vq_n, vs_n = _quant_heads(v_new)
-            k = scatter(cache["k"], kq_n, slots)
-            v = scatter(cache["v"], vq_n, slots)
-            ks = scatter(cache["ks"], ks_n, slots)
-            vs = scatter(cache["vs"], vs_n, slots)
-            new_cache = {"k": k, "v": v, "ks": ks, "vs": vs, "kpos": kpos}
-            out = _sdpa_int8(q, k, ks, v, vs, bias, cfg)
+        if "ks" in c:                                    # int8 cache path
+            out = _sdpa_int8(q, c["k"], c["ks"], c["v"], c["vs"], bias, cfg)
         else:
-            k = scatter(cache["k"], k_new, slots)
-            v = scatter(cache["v"], v_new, slots)
-            new_cache = {"k": k, "v": v, "kpos": kpos}
-            out = _sdpa(q, k, v, bias, cfg)
+            out = _sdpa(q, c["k"], c["v"], bias, cfg, kv="bksd")
     else:                                                # full sequence
         pos1 = positions[0]
         k, v = k_new, v_new
@@ -334,7 +346,8 @@ def attention(p, x, cfg, wbits=8, abits=8, *, positions, causal: bool = True,
                     else jnp.zeros((x.shape[1], x.shape[1]), jnp.float32))
             out = _sdpa(q, k, v, bias, cfg)
         if cache is not None:                            # prefill: fill cache
-            new_cache = prefill_cache_insert(cache, k_new, v_new, positions)
+            new_cache = prefill_cache_insert(cache, layer, k_new, v_new,
+                                             positions)
 
     if valid is not None:
         out = jnp.where(valid[..., None], out, 0).astype(out.dtype)
@@ -342,9 +355,10 @@ def attention(p, x, cfg, wbits=8, abits=8, *, positions, causal: bool = True,
     return y, new_cache
 
 
-def prefill_cache_insert(cache_layer: dict, k: jnp.ndarray, v: jnp.ndarray,
+def prefill_cache_insert(cache: dict, layer, k: jnp.ndarray, v: jnp.ndarray,
                          positions: jnp.ndarray) -> dict:
-    """Write a full prefill's k/v (B,S,KV,hd) into a fresh layer cache.
+    """Write a full prefill's k/v (B,S,KV,hd) into layer ``layer`` of a
+    fresh stacked cache (slots from 0; the rest of the stack untouched).
 
     ``positions`` (B, S) or (1, S) may differ per row: padded tokens at
     EMPTY_POS land in the cache as EMPTY_POS slots, which the decode
@@ -352,7 +366,7 @@ def prefill_cache_insert(cache_layer: dict, k: jnp.ndarray, v: jnp.ndarray,
     special-cased.  When the prompt buffer exceeds the ring capacity,
     each row keeps its own last ``Sc`` *valid* tokens (a per-row gather —
     a uniform tail slice would keep only padding for short rows)."""
-    Sc = cache_layer["k"].shape[1]
+    Sc = cache["k"].shape[3]
     B, S = k.shape[0], k.shape[1]
     keep = min(S, Sc)
     positions = jnp.broadcast_to(positions, (B, S)).astype(jnp.int32)
@@ -365,27 +379,15 @@ def prefill_cache_insert(cache_layer: dict, k: jnp.ndarray, v: jnp.ndarray,
         kpos_new = jnp.take_along_axis(positions, idx, axis=1)
         k_keep = jnp.take_along_axis(k, idx[..., None, None], axis=1)
         v_keep = jnp.take_along_axis(v, idx[..., None, None], axis=1)
-    kpos = jax.lax.dynamic_update_slice(cache_layer["kpos"], kpos_new,
-                                        (0, 0))
-    if "ks" in cache_layer:                              # int8 cache
-        kq, ks = _quant_heads(k_keep)
-        vq, vs = _quant_heads(v_keep)
-        return {
-            "k": jax.lax.dynamic_update_slice(cache_layer["k"], kq,
-                                              (0, 0, 0, 0)),
-            "v": jax.lax.dynamic_update_slice(cache_layer["v"], vq,
-                                              (0, 0, 0, 0)),
-            "ks": jax.lax.dynamic_update_slice(cache_layer["ks"], ks,
-                                               (0, 0, 0)),
-            "vs": jax.lax.dynamic_update_slice(cache_layer["vs"], vs,
-                                               (0, 0, 0)),
-            "kpos": kpos,
-        }
-    ck = jax.lax.dynamic_update_slice(
-        cache_layer["k"], k_keep, (0, 0, 0, 0))
-    cv = jax.lax.dynamic_update_slice(
-        cache_layer["v"], v_keep, (0, 0, 0, 0))
-    return {"k": ck, "v": cv, "kpos": kpos}
+    new = {"k": k_keep, "v": v_keep}
+    if "ks" in cache:                                    # int8 cache
+        new["k"], new["ks"] = _quant_heads(k_keep)
+        new["v"], new["vs"] = _quant_heads(v_keep)
+    new = {name: jnp.swapaxes(a, 1, 2) for name, a in new.items()}  # heads
+    new["kpos"] = kpos_new                                # before slots
+    return {name: jax.lax.dynamic_update_index_in_dim(
+        buf, new[name].astype(buf.dtype), layer, 0)
+        for name, buf in cache.items()}
 
 
 # ---------------------------------------------------------------------------
@@ -404,12 +406,12 @@ def mlp(p, x, cfg, wbits=8, abits=8):
 
 
 def block(p, x, cfg, wbits=8, abits=8, *, positions, causal=True,
-          cache=None, t=None, mlp_fn=None):
+          cache=None, layer=None, t=None, mlp_fn=None):
     """Pre-norm residual block.  Returns (x, new_cache, aux)."""
     h, new_cache = attention(p["attn"], cm.apply_norm(p["ln1"], x, cfg.norm_type,
                                                       cfg.norm_eps),
                              cfg, wbits, abits, positions=positions,
-                             causal=causal, cache=cache, t=t)
+                             causal=causal, cache=cache, layer=layer, t=t)
     x = x + h
     fn = mlp_fn if mlp_fn is not None else mlp
     out = fn(p["mlp"], cm.apply_norm(p["ln2"], x, cfg.norm_type, cfg.norm_eps),

@@ -155,7 +155,7 @@ def test_sliding_window_ring_buffer():
     n = lm.n_bit_slots(cfg)
     wvec = avec = jnp.full((n,), 8, jnp.int32)
     cache = lm.empty_cache(cfg, 1, 64)
-    assert cache["k"].shape[2] == cfg.sliding_window   # bounded!
+    assert cache["k"].shape[3] == cfg.sliding_window   # bounded!
     tok = jnp.zeros((1, 1), jnp.int32)
     for t in range(20):                                # > 2x window
         logits, cache = lm.decode_step(params, tok, jnp.asarray(t), cache,

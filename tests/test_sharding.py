@@ -67,10 +67,10 @@ def test_expert_stack_sharded_both_axes():
 
 def test_kv_cache_spec_long_context():
     """B=1 long decode shards the SEQUENCE over dp instead of batch."""
-    spec = shd._kv_cache_spec(MESH, (48, 1, 524288, 8, 128))
-    assert spec == P(None, None, "data", None, "model")
-    spec = shd._kv_cache_spec(MESH, (48, 128, 32768, 16, 128))
-    assert spec == P(None, "data", None, "model", None)
+    spec = shd._kv_cache_spec(MESH, (48, 1, 8, 524288, 128))
+    assert spec == P(None, None, None, "data", "model")
+    spec = shd._kv_cache_spec(MESH, (48, 128, 16, 32768, 128))
+    assert spec == P(None, "data", "model", None, None)
 
 
 def test_opt_int8_codec_mirrors_params():

@@ -180,9 +180,9 @@ def test_post_rejection_cache_bitexact(served):
     kpos_b = np.asarray(pool_b.cache["kpos"])
     np.testing.assert_array_equal(kpos_a, kpos_b)   # rollback left no trace
     visible = kpos_a != EMPTY_POS                   # (L, 1, Sc)
-    for leaf in ("k", "v"):
-        a = np.asarray(pool_a.cache[leaf])
-        b = np.asarray(pool_b.cache[leaf])
+    for leaf in ("k", "v"):                         # (L, 1, KV, Sc, hd)
+        a = np.moveaxis(np.asarray(pool_a.cache[leaf]), 3, 2)
+        b = np.moveaxis(np.asarray(pool_b.cache[leaf]), 3, 2)
         np.testing.assert_array_equal(a[visible], b[visible])
 
 

@@ -19,6 +19,7 @@ import re
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
@@ -142,15 +143,13 @@ def _weight_producers(hlo: str):
                                else define.group(1))
 
 
-def test_serve_decode_reads_each_weight_as_stored(one_chip):
-    """A two-layer serve decode step at qwen3-4b's widths (48 slots, the
-    int8 menu): every linear is one Mosaic call, and no fusion feeding a
-    kernel its weight requantizes it (no clamp, no arithmetic shift) —
-    the slice of the layer stack goes to the kernel as stored."""
+def _compile_decode_block(one_chip, cfg, n_slots: int, max_len: int,
+                          decode_block: int):
+    """The serve engine's decode block (``ServeEngine._decode_scan``, the
+    int8 menu) compiled for the described chip from shapes alone; returns
+    (compiled, number of weight leaves, abstract cache)."""
     from repro.models import lm
     from repro.serve.engine import ServeEngine
-    cfg = dataclasses.replace(configs.get("qwen3_4b"), n_layers=2,
-                              vocab_size=4096)
 
     def abstract(tree):
         return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
@@ -160,26 +159,105 @@ def test_serve_decode_reads_each_weight_as_stored(one_chip):
         lambda: lm.init_serve_params(cfg, jax.random.PRNGKey(0))))
     ctl = pol.BudgetController({"int8": pol.per_layer([8], [8], "int8")},
                                {"int8": 1.0}, cfg.n_layers)
-    B, L, max_len = 48, cfg.n_layers, 128
+    B, L = n_slots, cfg.n_layers
     eng = ServeEngine(cfg, qp, max_len=max_len, controller=ctl, n_slots=B,
-                      prefill_len=64, decode_block=1)
+                      prefill_len=64, decode_block=decode_block)
     cache = abstract(jax.eval_shape(lambda: lm.empty_cache(cfg, B, max_len)))
     keys = abstract(jax.eval_shape(
-        lambda: jax.random.split(jax.random.PRNGKey(0), 1)))
+        lambda: jax.random.split(jax.random.PRNGKey(0), decode_block)))
     ops.set_force_pallas(True)
     try:
         with eng.compute_ctx():
-            hlo = eng._decode_scan.lower(
+            compiled = eng._decode_scan.lower(
                 qp, _spec((B, 1), jnp.int32, one_chip),
                 _spec((B,), jnp.int32, one_chip), cache,
                 _spec((B, L), jnp.int32, one_chip),
                 _spec((B, L), jnp.int32, one_chip),
                 _spec((B,), jnp.float32, one_chip),
-                _spec((B,), jnp.int32, one_chip), keys).compile().as_text()
+                _spec((B,), jnp.int32, one_chip), keys).compile()
     finally:
         ops.set_force_pallas(None, interpret=ops.INTERPRET_ENV)
+    return compiled, len(jax.tree.leaves(qp)), cache
+
+
+def test_serve_decode_reads_each_weight_as_stored(one_chip):
+    """A two-layer serve decode step at qwen3-4b's widths (48 slots, the
+    int8 menu): every linear is one Mosaic call, and no fusion feeding a
+    kernel its weight requantizes it (no clamp, no arithmetic shift) —
+    the slice of the layer stack goes to the kernel as stored."""
+    cfg = dataclasses.replace(configs.get("qwen3_4b"), n_layers=2,
+                              vocab_size=4096)
+    hlo = _compile_decode_block(one_chip, cfg, 48, 128, 1)[0].as_text()
     found = list(_weight_producers(hlo))
     assert len(found) == 7, [k for k, _, _ in found]   # q k v o gate up down
     for kernel, weight, body in found:
         for op in ("clamp", "shift-right-arithmetic"):
             assert op not in body, (kernel, weight, op)
+
+
+_BYTES = {"pred": 1, "s8": 1, "u8": 1, "bf16": 2, "s32": 4, "u32": 4,
+          "f32": 4}
+
+
+def _ring_copies(hlo: str, n_slots: int, ring: int, least: int):
+    """Materialized copies, dynamic slices and dynamic-update-slices of
+    the KV ring: instructions of the compiled HLO outside fused
+    computations that are (or fuse) one of those ops and produce an array
+    with the slot and ring-length dims of ``least`` bytes or more."""
+    comps = dict(re.findall(r"^(?:ENTRY )?%?([\w.\-]+) [^\n]*\{\n(.*?)\n\}",
+                            hlo, re.S | re.M))
+    fused = set(re.findall(r"kind=k\w+, calls=%([\w.\-]+)", hlo))
+    moves = ("copy", "dynamic-slice", "dynamic-update-slice")
+    inst = re.compile(r"^\s*(?:ROOT )?%([\w.\-]+) = (\w+)\[([\d,]*)\]\S* "
+                      r"([\w\-]+)\(")
+    for name, body in comps.items():
+        if name in fused:
+            continue
+        for line in body.splitlines():
+            m = inst.match(line)
+            if not m or m.group(2) not in _BYTES:
+                continue
+            dims = [int(d) for d in m.group(3).split(",") if d]
+            op = m.group(4)
+            if op == "fusion":
+                called = re.search(r"calls=%([\w.\-]+)", line).group(1)
+                ops_in = set(re.findall(r"\]\S* ([\w\-]+)\(", comps[called]))
+                if not ops_in & set(moves):
+                    continue
+            elif op not in moves:
+                continue
+            if (n_slots in dims and ring in dims
+                    and int(np.prod(dims)) * _BYTES[m.group(2)] >= least):
+                yield m.group(1), op, m.group(2), dims
+
+
+@pytest.mark.parametrize("arch,n_layers,max_len", [
+    ("qwen3_4b", 2, 768), ("granite_4_0_h_micro", 20, 2560)])
+def test_decode_block_keeps_kv_ring_in_place(one_chip, arch, n_layers,
+                                             max_len):
+    """The decode block at the cells' shapes (48 slots, decode_block 8,
+    full widths, fewer layers) carries the KV ring through its step and
+    layer scans as one buffer, updated in place: every cache leaf's
+    output aliases its input, nothing copies or slices out an array as
+    large as one layer's K slab, and no temporary grows with the ring.
+
+    Granite compiles two periods: a one-period scan runs once, and the
+    compiler then lays its ring out afresh, copying it in and out (the
+    served model has four).  Its temporaries also hold the period scan's
+    weight slices, so the ring's share is read as the growth of the
+    temporaries from a 128-position ring to the full one."""
+    cfg = dataclasses.replace(configs.get(arch), n_layers=n_layers)
+    B = 48
+    compiled, n_q, cache = _compile_decode_block(one_chip, cfg, B, max_len, 8)
+    ring = cache["kv"] if "kv" in cache else cache
+    k = ring["k"]
+    slab = int(np.prod(k.shape[1:])) * k.dtype.itemsize
+    hlo = compiled.as_text()
+    header = hlo.splitlines()[0]
+    for i in range(len(jax.tree.leaves(cache))):    # outputs (tok, t, cache..)
+        assert f"{{{2 + i}}}: ({n_q + 2 + i}, {{}}" in header, i
+    assert list(_ring_copies(hlo, B, max_len, slab)) == []
+    short = _compile_decode_block(one_chip, cfg, B, 128, 8)[0]
+    grown = (compiled.memory_analysis().temp_size_in_bytes
+             - short.memory_analysis().temp_size_in_bytes)
+    assert grown < slab, (grown, slab)
