@@ -12,7 +12,7 @@ constants against the paper's own published numbers:
 
 Run it to regenerate the constants and their residuals; the values frozen
 into `apsim/energy.py` come from exactly this fit (single fit — nothing
-downstream re-tunes).  `python -m benchmarks.calibrate`
+downstream re-tunes).  `python -m paper.calibrate`
 """
 from __future__ import annotations
 

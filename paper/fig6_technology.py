@@ -46,10 +46,6 @@ def main() -> int:
     return 0 if (worst < 0.30 and trend_ok and vs_ok) else 1
 
 
-if __name__ == "__main__":
-    raise SystemExit(main())
-
-
 def voltage_scaling_check() -> bool:
     """Paper §V.A: scaling SRAM VDD 1.0 -> 0.5 V (write energy 0.24 ->
     0.06 fJ) saves < 0.1% end-to-end — compares dominate once writes are
@@ -63,3 +59,7 @@ def voltage_scaling_check() -> bool:
     print(f"voltage_scaling,energy_saving_frac,{saving:.5f},"
           f"err_prob,{scaled_tech.write_error_prob}")
     return 0.0 <= saving < 0.005
+
+
+if __name__ == "__main__":
+    raise SystemExit(main())

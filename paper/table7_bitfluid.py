@@ -15,7 +15,7 @@ from __future__ import annotations
 from repro.apsim.energy import SRAM
 from repro.apsim.mapper import LR_CONFIG, simulate_network
 from repro.apsim.workloads import (HAWQV3_METADATA, HAWQV3_RESNET18,
-                                   gemm_layers, per_layer_bits, resnet18)
+                                   per_layer_bits, resnet18)
 
 PAPER = {  # constraint: (norm_energy, norm_latency, edp)
     "int4": (3.29, 1.004, 0.58),
@@ -24,9 +24,6 @@ PAPER = {  # constraint: (norm_energy, norm_latency, edp)
     "low": (1.90, 1.004, 1.00),
     "int8": (1.0, 1.0, 1.91),
 }
-
-LAST_RESULTS: dict = {}
-
 
 def run():
     layers = resnet18()
@@ -83,12 +80,6 @@ def main(full: bool = True) -> int:
     print(f"check,latency_spread,{lat_spread:.3f}")
     print(f"check,serve_traces,{n_traces}")
     print(f"check,fidelity_int8>{fid['int8']:.4f}>int4>{fid['int4']:.4f}")
-    LAST_RESULTS.clear()
-    LAST_RESULTS.update({
-        "serve_traces": n_traces,
-        "serve_fidelity": {k: round(v, 4) for k, v in fid.items()},
-        "edp_rel": {k: round(edps[k] / base.edp, 3) for k in edps},
-    })
     return 0 if ok else 1
 
 

@@ -3,8 +3,7 @@
 ``run_suite`` executes the AST linter, the retrace auditor, the
 sharding checker, and the ledger auditor, applies the checked-in
 baseline, and reports a single ok/fail — the same entry the
-``repro.launch.analyze`` CLI, the CI ``analysis`` job, and
-``benchmarks/compare.py``'s baseline-update guard all use.
+``repro.launch.analyze`` CLI and the CI ``analysis`` job use.
 """
 from __future__ import annotations
 

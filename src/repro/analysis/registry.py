@@ -88,7 +88,7 @@ DEVICE_METHODS = frozenset({
     "resolve", "shard_bits", "shard_budgets", "shard_batch", "device_put",
     # ServeEngine compiled programs + helpers
     "_prefill", "_prefill_row", "_decode_scan", "_decode_scan_sh",
-    "_decode_one", "_draft", "_verify", "_sample_first", "_extend_row",
+    "_draft", "_verify", "_sample_first", "_extend_row",
     "_bits", "_batch_bits", "_draft_bits", "_split_key",
     # CNN compiled program
     "_fwd",
@@ -146,10 +146,9 @@ LEDGER_WAIVED: Dict[str, str] = {
                   "launch CLIs' per-request tables",
     "cached_mean_wbits": "prefix-cache precision introspection in "
                          "launch/serve.py --prefix-cache ledger",
-    "cached_cost": "hit repricing vs miss pricing in tests and the "
-                   "prefix-cache benchmark",
-    "cache_hit": "hit-kind split in benchmarks/prefix_cache.py and the "
-                 "launch ledger",
+    "cached_cost": "hit repricing vs miss pricing in "
+                   "tests/test_prefix_cache.py",
+    "cache_hit": "hit-kind assertions in tests/test_prefix_cache.py",
     "planned_units": "axis_planned() admission charge, reconciled in "
                      "ServeRuntime.finish_record",
     "slot": "slot lifecycle bookkeeping in ServeEngine._admit/_finish",
@@ -158,8 +157,8 @@ LEDGER_WAIVED: Dict[str, str] = {
     "finished_tick": "latency_ticks property -> traffic.Collector "
                      "tick-domain latency percentiles",
     "finished_s": "latency_s property -> wall-clock latency reporting",
-    "spec_k": "per-request draft-depth reporting in "
-              "benchmarks/spec_decode.py",
+    "spec_k": "axis_planned() draft charge and the per-request unit "
+              "checks in tests/test_spec_decode.py",
     "planned_spec_rounds": "axis_planned() speculative charge, "
                            "reconciled in finish_record",
     "planned_spec_tokens": "axis_planned() speculative charge, "
@@ -167,8 +166,10 @@ LEDGER_WAIVED: Dict[str, str] = {
     # ImageStats-only fields (CNN serve writes them through the same
     # record type family)
     "index": "batch-position bookkeeping in CNNServeEngine.serve",
-    "wbits": "per-image config introspection (tests, table7 benchmark)",
-    "abits": "per-image config introspection (tests, table7 benchmark)",
+    "wbits": "per-image config introspection (chip_smoke.py's CNN "
+             "phase, bench/kinds/cnn.py)",
+    "abits": "per-image config introspection (chip_smoke.py's CNN "
+             "phase, bench/kinds/cnn.py)",
 }
 
 

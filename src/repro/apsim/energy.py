@@ -37,7 +37,7 @@ class TechParams:
     write_error_prob: float = 0.0
 
 
-# Per-cell compare energy: CALIBRATED (single fit, benchmarks/calibrate.py)
+# Per-cell compare energy: CALIBRATED (single fit, paper/calibrate.py)
 # against (a) Fig. 6 ReRAM/SRAM VGG16 energy ratios 80.9x@2b..63.1x@8b and
 # (b) the paper's absolute LR/SRAM ResNet50 energies 0.009J@2b / 0.095J@8b.
 # Result: ratios within +/-8%, absolute energies within 4%.

@@ -39,9 +39,7 @@ actually emit; rows whose bits fall between families snap UP to the next
 family, and rows ABOVE the largest family clamp down to it — so a family
 set must always include its policy's widest bit-width (engines derive it
 from the controller, which guarantees this; results are bit-exact
-whenever the bits are in the set).  The historical
-per-row vmap path is kept behind ``set_row_dispatch("vmap")`` as the
-benchmark baseline.
+whenever the bits are in the set).
 """
 from __future__ import annotations
 
@@ -68,7 +66,6 @@ _INTERPRET = INTERPRET_ENV
 # Distinct weight bit-widths the grouped per-row path specializes for.
 BIT_FAMILIES = (2, 3, 4, 6, 8)
 _families: Sequence[int] = BIT_FAMILIES
-_row_dispatch = "grouped"
 
 
 def set_force_pallas(v: Optional[bool], interpret: Optional[bool] = None
@@ -160,31 +157,6 @@ def token_scale_mode():
         yield
     finally:
         _token_scales = prev
-
-
-def set_row_dispatch(mode: str) -> None:
-    """'grouped' (default) or 'vmap' (the per-row baseline, kept for
-    benchmarks/parity tests).  Read at trace time."""
-    global _row_dispatch
-    if mode not in ("grouped", "vmap"):
-        raise ValueError(f"row dispatch must be 'grouped' or 'vmap', "
-                         f"got {mode!r}")
-    _row_dispatch = mode
-
-
-def get_row_dispatch() -> str:
-    return _row_dispatch
-
-
-@contextlib.contextmanager
-def row_dispatch(mode: str):
-    global _row_dispatch
-    prev = _row_dispatch
-    set_row_dispatch(mode)
-    try:
-        yield
-    finally:
-        _row_dispatch = prev
 
 
 def _pad_to(x: jnp.ndarray, mults) -> jnp.ndarray:
@@ -445,7 +417,7 @@ def serve_linear(p: dict, x: jnp.ndarray, wbits=8, abits=8, *,
 
     ``wbits``/``abits`` scalars (Python ints or traced) take the container
     path; ``(B,)`` vectors (per-request precision) take the bit-grouped
-    batch path (or the vmap baseline under ``set_row_dispatch("vmap")``).
+    batch path.
     Returns float32; callers cast to their activation dtype.
     """
     if getattr(wbits, "ndim", 0) >= 1 or getattr(abits, "ndim", 0) >= 1:
@@ -498,16 +470,11 @@ def _family_index(wb: jnp.ndarray, fams) -> jnp.ndarray:
 
 
 def _serve_linear_rows(p, x, wbits, abits, interpret):
-    """Per-row precision: grouped (one GEMM per static bit family) or the
-    vmap baseline (one weight requant per row)."""
+    """Per-row precision: one grouped GEMM per static bit family, each
+    row's result gathered from its family's accumulator."""
     B = x.shape[0]
     wb = jnp.broadcast_to(jnp.asarray(wbits, jnp.int32), (B,))
     ab = jnp.broadcast_to(jnp.asarray(abits, jnp.int32), (B,))
-    if _row_dispatch == "vmap":
-        return jax.vmap(
-            lambda xr, w, a: serve_linear(p, xr, w, a, interpret=interpret)
-        )(x, wb, ab)
-
     if "q4" in p:
         qw, from_bits = bf.unpack_int4_halves(p["q4"]), 4
     else:

@@ -9,12 +9,11 @@ signature per compiled entrypoint across every config × budget × k ×
 every 1/2/4/8-device mesh for all ten FULL configs), and the ledger
 auditor (every ``CostRecord`` field written in ``serve/`` is consumed
 by ``aggregate()`` or waived).  Exit status 0 iff no fresh findings and
-no stale baseline entries — the blocking CI ``analysis`` job and
-``benchmarks/compare.py``'s baseline-update guard both ride on it.
+no stale baseline entries — the blocking CI ``analysis`` job rides
+on it.
 
-``--json PATH`` writes the machine-readable result (compare.py reads
-it to stamp analysis status into the step summary without re-running
-the suite).
+``--json PATH`` writes the machine-readable result (the CI
+``analysis`` job uploads it as its status artifact).
 """
 from __future__ import annotations
 

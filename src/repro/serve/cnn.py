@@ -201,7 +201,7 @@ def hawq_fidelity_sweep(network: str = "resnet18", image: int = 32,
     (fake-quant-identity) reference — the functional accuracy axis of
     the Table VII accuracy-vs-EDP reproduction.  ``n_traces`` counts
     compiles across all five configuration switches; 1 is the
-    zero-retrace claim (``benchmarks/table7_bitfluid.py`` gates on it,
+    zero-retrace claim (``paper/table7_bitfluid.py`` gates on it,
     ``examples/mixed_precision_resnet18.py`` prints it).
     """
     from repro.apsim.workloads import HAWQV3_RESNET18, per_layer_bits

@@ -28,8 +28,7 @@ block, per-row sampling, and the KV cache pool.
 
 The legacy whole-batch API (``set_budget``/``generate``) is kept — it
 accepts a per-request budget *vector* and runs the same scan-fused
-decode (``fused=False`` preserves the per-token Python loop for the
-benchmark baseline in benchmarks/serve_throughput.py).
+decode.
 """
 from __future__ import annotations
 
@@ -259,13 +258,6 @@ class ServeEngine(ServeRuntime):
             (tok, t, cache), toks = jax.lax.scan(step, (tok, t, cache), keys)
             return tok, t, cache, jnp.moveaxis(toks, 0, 1)   # (B, steps)
 
-        def _decode_one(q, tok, t, cache, wv, av, temp, topk, key):
-            # per-token baseline (benchmarks) — same math, no scan fusion
-            self.stats.trace("decode")
-            logits, cache = lm.decode_step(q, tok, t, cache, cfg, wv, av)
-            nxt = _sample_tokens(logits[:, -1], key, temp, topk)
-            return nxt[:, None], t + 1, cache, nxt
-
         def _draft_scan(q, tok, t, cache, wv, av, temp, topk, keys):
             # speculative self-draft: SPEC_K_MAX scan-fused decode steps
             # at the engine's LOW draft bits (one program for every k —
@@ -430,7 +422,6 @@ class ServeEngine(ServeRuntime):
                                P(dpx, None)),
                     check_vma=False),
                 donate_argnums=(3,))
-        self._decode_one = jax.jit(_decode_one, donate_argnums=(3,))
         self._draft = jax.jit(_draft_scan, donate_argnums=(3,))
         self._verify = jax.jit(_spec_verify, donate_argnums=(5,))
         self._sample_first = jax.jit(_sample_first)
@@ -523,8 +514,7 @@ class ServeEngine(ServeRuntime):
     # ------------------------------------------------------------------
 
     def generate(self, batch: Dict[str, jnp.ndarray], steps: int, *,
-                 temperature=None, top_k=None, fused: bool = True
-                 ) -> jnp.ndarray:
+                 temperature=None, top_k=None) -> jnp.ndarray:
         """Generate ``steps`` tokens for one synchronous batch; returns
         (B, steps) ids.  Greedy unless per-row temperature/top_k given."""
         if isinstance(self.controller, FluidController):
@@ -535,9 +525,9 @@ class ServeEngine(ServeRuntime):
                 "FluidController's SLO window is only charged by the "
                 "continuous scheduler — use submit()/run()")
         with self.compute_ctx():
-            return self._generate(batch, steps, temperature, top_k, fused)
+            return self._generate(batch, steps, temperature, top_k)
 
-    def _generate(self, batch, steps, temperature, top_k, fused):
+    def _generate(self, batch, steps, temperature, top_k):
         B, S = batch["tokens"].shape
         prefix = self.cfg.n_prefix_tokens if self.cfg.family == "vlm" else 0
         temp = jnp.zeros((B,), jnp.float32) if temperature is None else \
@@ -559,19 +549,9 @@ class ServeEngine(ServeRuntime):
         keys = self._split_key(steps)
         tok = self._sample_first(logits, keys[0], temp, topk)[:, None]
         t = jnp.full((B,), S + prefix, jnp.int32)
-        if fused:
-            _, _, cache, toks = self._decode_scan(
-                self.qparams, tok, t, cache, wv, av, temp, topk,
-                keys[1:steps])
-            out = jnp.concatenate([tok, toks], axis=1)
-        else:
-            out = [tok]
-            for i in range(steps - 1):
-                tok, t, cache, _ = self._decode_one(
-                    self.qparams, tok, t, cache, wv, av, temp, topk,
-                    keys[1 + i])
-                out.append(tok)
-            out = jnp.concatenate(out, axis=1)
+        _, _, cache, toks = self._decode_scan(
+            self.qparams, tok, t, cache, wv, av, temp, topk, keys[1:steps])
+        out = jnp.concatenate([tok, toks], axis=1)
         self.stats.tokens += B * steps
         return out
 

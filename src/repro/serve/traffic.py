@@ -26,9 +26,9 @@ on:
     latency (ticks) and EDP, queue depth over time, unserved/starvation
     counts, and mean resolved bits per arrival window.
 
-``benchmarks/traffic_elasticity.py`` drives the spike-response and
-hourly-elasticity experiments on top; ``launch/serve.py --trace`` replays
-a pattern through one LM engine via ``ServeRuntime.submit_at``/``run``.
+``launch/serve.py --trace`` replays a pattern through one LM engine via
+``ServeRuntime.submit_at``/``run``; ``tests/test_traffic.py`` holds the
+spike-response and tick-windowed elasticity claims.
 """
 from __future__ import annotations
 

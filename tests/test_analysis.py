@@ -413,32 +413,6 @@ def test_run_suite_fast_passes_ok():
     assert d["ok"] and set(d["passes"]) == {"lint", "ledger"}
 
 
-def test_compare_refuses_baseline_update_on_analysis_failure(tmp_path):
-    import importlib.util
-    import os
-    spec = importlib.util.spec_from_file_location(
-        "bench_compare", os.path.join(common.repo_root(), "benchmarks",
-                                      "compare.py"))
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-
-    bench = tmp_path / "bench.json"
-    bench.write_text(json.dumps({"suite": "smoke", "modules": {}}))
-    base = tmp_path / "baseline.json"
-    bad = tmp_path / "bad.json"
-    bad.write_text(json.dumps({"ok": False, "passes": {"lint": {}}}))
-    rc = mod.main(["--update-baseline", "--baseline", str(base),
-                   "--current", str(bench),
-                   "--analysis-status", str(bad)])
-    assert rc == 2 and not base.exists()
-    good = tmp_path / "good.json"
-    good.write_text(json.dumps({"ok": True, "passes": {}}))
-    rc = mod.main(["--update-baseline", "--baseline", str(base),
-                   "--current", str(bench),
-                   "--analysis-status", str(good)])
-    assert rc == 0 and base.exists()
-
-
 def test_cli_exit_codes(tmp_path):
     from repro.launch import analyze
     out = tmp_path / "status.json"

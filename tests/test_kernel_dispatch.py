@@ -89,7 +89,7 @@ def test_traced_scalar_bits_parity(rng, container):
 
 
 # ---------------------------------------------------------------------------
-# Per-row bits: grouped dispatch vs the vmap baseline
+# Per-row bits: grouped dispatch vs the inline per-row vmap oracle
 # ---------------------------------------------------------------------------
 
 @pytest.mark.parametrize("container", ["int8", "int4"])
@@ -102,10 +102,6 @@ def test_grouped_dispatch_matches_vmap_oracle(rng, container, seq):
     got = cm.apply_linear(p, x, wb, ab)
     want = _inline_vmap(p, x, wb, ab)
     np.testing.assert_array_equal(_f32(got), _f32(want))
-    # and through ops' own vmap baseline
-    with ops.row_dispatch("vmap"):
-        base = cm.apply_linear(p, x, wb, ab)
-    np.testing.assert_array_equal(_f32(base), _f32(want))
 
 
 def test_grouped_dispatch_scalar_abits_vector_wbits(rng):
@@ -141,9 +137,6 @@ def test_bit_families_context_restores():
     assert ops.get_bit_families() == before
     with pytest.raises(ValueError):
         ops.set_bit_families(())
-    with pytest.raises(ValueError):
-        ops.set_row_dispatch("loop")
-    assert ops.get_row_dispatch() == "grouped"
 
 
 def test_grouped_dispatch_zero_retrace(rng):
