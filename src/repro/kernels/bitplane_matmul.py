@@ -17,7 +17,9 @@ bit planes and issues one int8 matmul per plane:
 * Weight fluidity happens here, on the tile: the kernel takes the container
   as stored and its ``(to_bits, from_bits)`` pair in SMEM (scalar
   prefetch, so static and traced bits share one kernel and no bit width
-  is baked into it).  At shift
+  is baked into it).  A layer scan hands it the whole ``(L, K, N)``
+  stack and the layer's index as a third SMEM scalar, which the
+  weight's index map reads: each tile is DMA'd from the stack itself.  At shift
   ``from_bits - to_bits <= 0`` the tile goes to the MXU untouched — no
   per-byte VPU work; above 0 it is requantized in VMEM with
   ``core.bitfluid.requant_shift``'s integer rounding first.  Skipping the
@@ -29,7 +31,9 @@ in VMEM across the K steps and accumulates there.  Requantization and plane
 extraction run ``_ROWS`` rows of the VMEM-resident tile at a time into int8
 scratch, so HBM traffic is the int8 container once — requantized copies and
 planes never reach HBM — and the int32 working set does not grow with the
-tile.  ops.py sizes the blocks by shape (``ops._bitplane_tiles``) and pads.
+tile.  ops.py sizes the blocks by shape (``ops._bitplane_tiles``) and pads
+the activations; a weight is never padded (an unaligned N runs a ragged
+last column block).
 """
 from __future__ import annotations
 
@@ -47,29 +51,35 @@ def _chunk_rows(bk: int) -> int:
     return min(bk, _ROWS)
 
 
-def vmem_bytes(bm: int, bn: int, bk: int, n_planes: int) -> int:
+def vmem_bytes(bm: int, bn: int, bk: int, n_planes: int,
+               k_minor: bool = False) -> int:
     """Upper estimate of the kernel's VMEM working set, in bytes: the
     double-buffered x, weight and int32 output blocks, the int8 scratch
     (requantized tile, and the planes when ``n_planes < 8``), and the
     int32 temporaries (one row chunk's requant or plane split, and the
-    matmul results)."""
+    matmul results).  The weight tile is (bk, bn), or (bn, bk) when
+    ``k_minor``."""
     tile = bk * bn
+    rows, cols = (bn, bk) if k_minor else (bk, bn)
     pipelined = 2 * (bm * bk + tile + 4 * bm * bn)
     scratch = tile * (1 if n_planes == 8 else 1 + n_planes)
-    temps = 6 * 4 * _chunk_rows(bk) * bn + 2 * 4 * bm * bn
+    temps = 6 * 4 * _chunk_rows(rows) * cols + 2 * 4 * bm * bn
     return pipelined + scratch + temps
 
 
-def vmem_limit_bytes(bm: int, bn: int, bk: int, n_planes: int) -> int:
+def vmem_limit_bytes(bm: int, bn: int, bk: int, n_planes: int,
+                     k_minor: bool = False) -> int:
     """The scoped-VMEM limit the kernel compiles under: its working set
     with a quarter more for the compiler's own scratch."""
-    ws = vmem_bytes(bm, bn, bk, n_planes)
+    ws = vmem_bytes(bm, bn, bk, n_planes, k_minor)
     return ws + ws // 4 + (1 << 20)
 
 
-def _dot(x, w):
-    return jax.lax.dot_general(x, w, dimension_numbers=(((1,), (0,)), ((), ())),
-                               preferred_element_type=jnp.int32)
+def _dot(x, w, k_minor: bool):
+    """x (bm, bk) @ the weight tile: (bk, bn), or (bn, bk) K-minor."""
+    return jax.lax.dot_general(
+        x, w, dimension_numbers=(((1,), (1 if k_minor else 0,)), ((), ())),
+        preferred_element_type=jnp.int32)
 
 
 def _row_loop(n_rows: int, rows: int, body) -> None:
@@ -94,7 +104,8 @@ def _requant_tile(w_ref, wq_ref, shift, to_bits) -> None:
     _row_loop(w_ref.shape[0], _chunk_rows(w_ref.shape[0]), body)
 
 
-def _plane_walk(x_ref, src_ref, planes_ref, o_ref, n_planes: int) -> None:
+def _plane_walk(x_ref, src_ref, planes_ref, o_ref, n_planes: int,
+                k_minor: bool) -> None:
     """o += sum_j w_j * (x @ plane_j) over the low ``n_planes``
     two's-complement field of ``src`` — the bit-serial walk, one int8
     matmul per plane (a loop, so the kernel holds one matmul)."""
@@ -110,14 +121,14 @@ def _plane_walk(x_ref, src_ref, planes_ref, o_ref, n_planes: int) -> None:
     def plane(j, carry):
         weight = jnp.where(j == n_planes - 1, -(1 << (n_planes - 1)),
                            jnp.left_shift(1, j))
-        o_ref[...] += weight * _dot(x_ref[...], planes_ref[j])
+        o_ref[...] += weight * _dot(x_ref[...], planes_ref[j], k_minor)
         return carry
 
     jax.lax.fori_loop(0, n_planes, plane, 0)
 
 
 def _kernel(bits_ref, x_ref, w_ref, o_ref, wq_ref, *planes_ref,
-            n_planes: int):
+            n_planes: int, k_minor: bool):
     @pl.when(pl.program_id(2) == 0)
     def _init():
         o_ref[...] = jnp.zeros_like(o_ref)
@@ -129,9 +140,10 @@ def _kernel(bits_ref, x_ref, w_ref, o_ref, wq_ref, *planes_ref,
         if n_planes == 8:
             # container width: the 8-plane walk reassembles the int8 word
             # exactly, so it degenerates to the MXU's native int8 matmul
-            o_ref[...] += _dot(x_ref[...], src_ref[...])
+            o_ref[...] += _dot(x_ref[...], src_ref[...], k_minor)
         else:
-            _plane_walk(x_ref, src_ref, planes_ref[0], o_ref, n_planes)
+            _plane_walk(x_ref, src_ref, planes_ref[0], o_ref, n_planes,
+                        k_minor)
 
     @pl.when(shift <= 0)
     def _as_stored():
@@ -144,39 +156,63 @@ def _kernel(bits_ref, x_ref, w_ref, o_ref, wq_ref, *planes_ref,
 
 
 @functools.partial(jax.jit, static_argnames=("n_planes", "bm", "bn", "bk",
-                                             "interpret"))
+                                             "k_minor", "interpret"))
 def bitplane_matmul(x_q: jnp.ndarray, w_q: jnp.ndarray, to_bits, from_bits=8,
-                    *, n_planes: int = 8, bm: int = 128, bn: int = 128,
-                    bk: int = 128, interpret: bool = False) -> jnp.ndarray:
+                    layer=0, *, n_planes: int = 8, bm: int = 128,
+                    bn: int = 128, bk: int = 128, k_minor: bool = False,
+                    interpret: bool = False) -> jnp.ndarray:
     """(M, K) int8 @ (K, N) ``from_bits`` container requantized to
     ``to_bits`` (int32 scalars) -> (M, N) int32, plane-serial.
 
-    Shapes must be multiples of the block sizes (ops.py pads).
+    ``w_q`` may instead be a layer stack (L, K, N): ``layer`` (int32)
+    rides in SMEM beside the bits, and the weight's index map picks that
+    layer's tiles, so the kernel reads them from the stack as stored and
+    nothing copies the layer out first.  ``k_minor``: the weight comes
+    transposed, (N, K) or (L, N, K), as a TPU lays out a K-minor stack
+    (the transpose of one is free), and each tile multiplies transposed.
+    M and K must be multiples of their blocks (ops.py pads the
+    activations); N need not be: the grid covers ``ceil(N / bn)`` column
+    blocks, and the last block's columns past N are never written to the
+    (M, N) result.
     """
     M, K = x_q.shape
-    K2, N = w_q.shape
+    w_dims = w_q.shape[-2:]
+    K2, N = w_dims[::-1] if k_minor else w_dims
     assert K == K2, (x_q.shape, w_q.shape)
-    assert M % bm == 0 and N % bn == 0 and K % bk == 0, (M, N, K, bm, bn, bk)
+    assert M % bm == 0 and K % bk == 0, (M, N, K, bm, bn, bk)
+    assert bn == N or bn % 128 == 0, (N, bn)
     assert 1 <= n_planes <= 8
-    scratch = [pltpu.VMEM((bk, bn), jnp.int8)]
+    tile = (bn, bk) if k_minor else (bk, bn)
+
+    def w_index(i, j, k):
+        return (j, k) if k_minor else (k, j)
+
+    if w_q.ndim == 3:
+        w_spec = pl.BlockSpec((pl.squeezed,) + tile,
+                              lambda i, j, k, s: (s[2],) + w_index(i, j, k))
+    else:
+        w_spec = pl.BlockSpec(tile, lambda i, j, k, s: w_index(i, j, k))
+    scratch = [pltpu.VMEM(tile, jnp.int8)]
     if n_planes < 8:
-        scratch.append(pltpu.VMEM((n_planes, bk, bn), jnp.int8))
+        scratch.append(pltpu.VMEM((n_planes,) + tile, jnp.int8))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
-        grid=(M // bm, N // bn, K // bk),
+        grid=(M // bm, pl.cdiv(N, bn), K // bk),
         in_specs=[
-            pl.BlockSpec((bm, bk), lambda i, j, k, b: (i, k)),
-            pl.BlockSpec((bk, bn), lambda i, j, k, b: (k, j)),
+            pl.BlockSpec((bm, bk), lambda i, j, k, s: (i, k)),
+            w_spec,
         ],
-        out_specs=pl.BlockSpec((bm, bn), lambda i, j, k, b: (i, j)),
+        out_specs=pl.BlockSpec((bm, bn), lambda i, j, k, s: (i, j)),
         scratch_shapes=scratch,
     )
+    scalars = jnp.stack([jnp.asarray(v, jnp.int32)
+                         for v in (to_bits, from_bits, layer)])
     return pl.pallas_call(
-        functools.partial(_kernel, n_planes=n_planes),
+        functools.partial(_kernel, n_planes=n_planes, k_minor=k_minor),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((M, N), jnp.int32),
         compiler_params=pltpu.CompilerParams(
-            vmem_limit_bytes=vmem_limit_bytes(bm, bn, bk, n_planes)),
+            vmem_limit_bytes=vmem_limit_bytes(bm, bn, bk, n_planes,
+                                              k_minor)),
         interpret=interpret,
-    )(jnp.stack([jnp.asarray(to_bits, jnp.int32),
-                 jnp.asarray(from_bits, jnp.int32)]), x_q, w_q)
+    )(scalars, x_q, w_q)

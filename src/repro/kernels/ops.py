@@ -207,45 +207,75 @@ def _lane_blocks(d: int, cap: Optional[int]):
     """(padded d, candidate blocks, largest first): multiples of 128 that
     divide d rounded up to 128, so an aligned dim is never padded (and no
     XLA op copies a weight before the kernel); a dim under 128 is one
-    block, as ``_block_dim`` sizes it."""
+    block of the whole dim."""
     if d < 128:
-        b = _block_dim(d)
-        return b, [b]
+        return d, [d]
     lanes = _round_up(d, 128) // 128
     return lanes * 128, [128 * m for m in range(lanes, 0, -1)
                          if lanes % m == 0 and (cap is None or 128 * m <= cap)]
 
 
-def _bitplane_tiles(M: int, N: int, K: int, n_planes: int) -> Tiles:
+def _bitplane_tiles(M: int, N: int, K: int, n_planes: int,
+                    k_minor: bool = False) -> Tiles:
     """Blocks for the serve GEMM kernel, a function of its shape alone.
 
     bm is the whole of M (rounded up to the int8 sublane tile of 32) up
     to 512; bk is the whole of K wherever the tile fits; bn is the widest
     lane-dense divisor of N up to 512 whose int8 tile stays within 4 MiB.
-    Every pair must keep the working set (``vmem_bytes``, which counts the
-    plane walk's scratch at ``n_planes < 8``) within the budget; bk shrinks
-    only when no bn fits.  qwen3-4b's decode layer: 65 grid steps."""
+    An N that is not a multiple of 128 (granite's in_proj, 8512) takes
+    any lane-dense width: the kernel's last column block runs ragged, and
+    ``np`` is N rounded up to it.  Every pair must keep the working set
+    (``vmem_bytes``, which counts the plane walk's scratch at
+    ``n_planes < 8``, and the tile's orientation, ``k_minor``) within the
+    budget; bk shrinks only when no bn fits.  qwen3-4b's decode layer: 65
+    grid steps."""
     nb = -(-M // _BM_MAX)
     bm = _round_up(-(-M // nb), 32)
-    np_, bns = _lane_blocks(N, _BN_MAX)
+    _, bns = _lane_blocks(N, _BN_MAX)
+    if N > 128 and N % 128:
+        bns = list(range(_BN_MAX, 0, -128))
     kp, bks = _lane_blocks(K, None)
     for bk in bks:
         for bn in bns:
             if (bk * bn <= _W_TILE_BYTES and
-                    _bitplane_vmem(bm, bn, bk, n_planes) <= _VMEM_BUDGET):
-                return Tiles(bm, bn, bk, nb * bm, np_, kp)
-    return Tiles(bm, bns[-1], bks[-1], nb * bm, np_, kp)
+                    _bitplane_vmem(bm, bn, bk, n_planes, k_minor)
+                    <= _VMEM_BUDGET):
+                return Tiles(bm, bn, bk, nb * bm, _round_up(N, bn), kp)
+    bn, bk = bns[-1], bks[-1]
+    return Tiles(bm, bn, bk, nb * bm, _round_up(N, bn), kp)
 
 
-def _bitplane(x_q, w, to_bits, *, n_planes, from_bits, interpret):
+def _k_minor(K: int, N: int) -> bool:
+    """Whether a TPU lays an int8 (L, K, N) stack out K-minor: its default
+    layout is the one its (32, 128) tiles pad less, K-minor on a tie when
+    N is not a multiple of 128 (granite's in_proj, N 8512, is K-minor).
+    A wrong guess costs a layout copy of the stack, never a wrong sum."""
+    k_minor = _round_up(N, 32) * _round_up(K, 128)
+    n_minor = _round_up(K, 32) * _round_up(N, 128)
+    return k_minor < n_minor or (k_minor == n_minor and N % 128 != 0)
+
+
+def _bitplane(x_q, w, to_bits, *, n_planes, from_bits, interpret,
+              layer=None):
+    """The kernel at its tiles.  ``w`` (K, N), or an (L, K, N) stack with
+    ``layer`` its index, goes to the kernel as stored: a K-minor stack as
+    its transpose (a free view of its layout), and only a K that is not a
+    whole number of blocks is padded (a 2-D weight; a stack never gets
+    here with one, see :func:`serve_linear`).  An unaligned N runs the
+    kernel's ragged last column block instead of a padded copy."""
     M, K = x_q.shape
-    N = w.shape[1]
-    t = _bitplane_tiles(M, N, K, n_planes)
+    N = w.shape[-1]
+    k_minor = layer is not None and _k_minor(K, N)
+    t = _bitplane_tiles(M, N, K, n_planes, k_minor)
+    if t.kp != K:
+        assert layer is None, (w.shape, t)
+        w = _pad_to(w, (t.kp, 1))
     xp = _pad_to(x_q, (t.mp, t.kp))
-    wp = _pad_to(w, (t.kp, t.np))
-    out = _bitplane_pallas(xp, wp, to_bits, from_bits, n_planes=n_planes,
-                           bm=t.bm, bn=t.bn, bk=t.bk, interpret=interpret)
-    return out[:M, :N]
+    out = _bitplane_pallas(xp, jnp.swapaxes(w, -1, -2) if k_minor else w,
+                           to_bits, from_bits, 0 if layer is None else layer,
+                           n_planes=n_planes, bm=t.bm, bn=t.bn, bk=t.bk,
+                           k_minor=k_minor, interpret=interpret)
+    return out[:M]
 
 
 def bitplane_matmul(x_q: jnp.ndarray, w_q: jnp.ndarray, *, n_planes: int = 8,
@@ -331,9 +361,12 @@ def _static_bits(b) -> Optional[int]:
 
 
 def int8_accum(x_q: jnp.ndarray, w: jnp.ndarray, to_bits, *,
-               from_bits: int = 8, interpret: bool = False) -> jnp.ndarray:
+               from_bits: int = 8, layer=None,
+               interpret: bool = False) -> jnp.ndarray:
     """int8 (M,K) @ a ``from_bits`` container (K,N) requantized to
-    ``to_bits`` -> int32 (M,N), through the kernel layer.
+    ``to_bits`` -> int32 (M,N), through the kernel layer.  With ``layer``
+    (int32), ``w`` is an (L, K, N) stack and layer ``layer`` of it is the
+    weight: the kernel reads it from the stack, the ref path indexes it.
 
     Static ``to_bits`` runs the plane-serial kernel at exactly that many
     bit planes (TPU cost ∝ assigned bits); traced bits run the
@@ -345,10 +378,12 @@ def int8_accum(x_q: jnp.ndarray, w: jnp.ndarray, to_bits, *,
     n = 8 if tb is None else min(max(tb, 1), 8)
     interpret = _interp(interpret)
     if not (use_pallas() or interpret):
+        if layer is not None:
+            w = jax.lax.dynamic_index_in_dim(w, layer, 0, keepdims=False)
         return kref.bitplane_matmul_ref(
             x_q, bf.requant_shift(w, to_bits, from_bits=from_bits), n)
     return _bitplane(x_q, w, to_bits, n_planes=n, from_bits=from_bits,
-                     interpret=interpret)
+                     interpret=interpret, layer=layer)
 
 
 def _epilogue(acc2, lead, x_scale, w_s, bias):
@@ -360,26 +395,30 @@ def _epilogue(acc2, lead, x_scale, w_s, bias):
     return y
 
 
-def _container_linear(x, qw, s, bias, *, from_bits, wbits, abits, interpret):
+def _container_linear(x, qw, s, bias, *, from_bits, wbits, abits, interpret,
+                      layer=None):
     x2 = x.astype(jnp.float32)
     x_scale = bf.symmetric_scale(x2, abits)           # per-tensor scalar
     x_q = bf.quantize(x2, x_scale, abits)
     w_s = bf.effective_scale(s, wbits, from_bits=from_bits)
     acc = int8_accum(x_q.reshape(-1, x.shape[-1]), qw, wbits,
-                     from_bits=from_bits, interpret=interpret)
+                     from_bits=from_bits, layer=layer, interpret=interpret)
     return _epilogue(acc, x.shape[:-1], x_scale, w_s, bias)
 
 
 def quant_linear(x: jnp.ndarray, q: jnp.ndarray, s: jnp.ndarray,
                  bias: Optional[jnp.ndarray] = None, *, wbits=8, abits=8,
-                 interpret: bool = False) -> jnp.ndarray:
-    """float (..., K) @ int8-container {q (K,N), s (1,N)} -> f32 (..., N).
+                 layer=None, interpret: bool = False) -> jnp.ndarray:
+    """float (..., K) @ int8-container {q (K,N), s (1,N)} -> f32 (..., N);
+    with ``layer``, ``q`` is an (L, K, N) stack and its layer ``layer``
+    is the weight.
 
     Dyadic requantization to ``wbits`` + dynamic ``abits`` activation
     quantization; bits may be Python ints (static → plane-serial kernel)
     or traced scalars (zero-recompilation switch)."""
     return _container_linear(x, q, s, bias, from_bits=8, wbits=wbits,
-                             abits=abits, interpret=_interp(interpret))
+                             abits=abits, interpret=_interp(interpret),
+                             layer=layer)
 
 
 def int4_linear(x: jnp.ndarray, q4: jnp.ndarray, s: jnp.ndarray,
@@ -411,15 +450,51 @@ def int4_linear(x: jnp.ndarray, q4: jnp.ndarray, s: jnp.ndarray,
                              interpret=interpret)
 
 
+_feeds: Optional[list] = None
+
+
+@contextlib.contextmanager
+def count_weight_feeds():
+    """Count the serve linears traced inside the context by how their
+    weight reaches the GEMM: yields ``[from_stack, from_copy]``, the
+    linears that took an (L, K, N) layer stack and its index, and those
+    given a 2-D weight (in a layer scan, a slice XLA copies out of the
+    stack).  A trace-time count: a compiled call adds nothing."""
+    global _feeds
+    prev, _feeds = _feeds, [0, 0]
+    try:
+        yield _feeds
+    finally:
+        _feeds = prev
+
+
+def _stack_or_slice(p: dict) -> dict:
+    """``p`` as the GEMM takes it: a stacked linear whose K the kernel
+    would pad becomes its layer's 2-D slice (no published width does; a
+    padded K needs a padded copy of the layer anyway)."""
+    if "layer" not in p:
+        return p
+    K = p["q"].shape[-2]
+    if _lane_blocks(K, None)[0] == K:
+        return p
+    q = jax.lax.dynamic_index_in_dim(p["q"], p["layer"], 0, keepdims=False)
+    return {k: v for k, v in dict(p, q=q).items() if k != "layer"}
+
+
 def serve_linear(p: dict, x: jnp.ndarray, wbits=8, abits=8, *,
                  interpret: bool = False) -> jnp.ndarray:
     """Serve-form linear dispatch: {"q","s"[,"b"]} or {"q4","s"[,"b"]}.
 
     ``wbits``/``abits`` scalars (Python ints or traced) take the container
     path; ``(B,)`` vectors (per-request precision) take the bit-grouped
-    batch path.
+    batch path.  A layer scan's linear is ``{"q": (L, K, N) stack, "s",
+    "layer": index}``: the stack goes whole to the GEMM, which reads the
+    layer's tiles from it (``models.transformer.cached_layer_scan``).
     Returns float32; callers cast to their activation dtype.
     """
+    p = _stack_or_slice(p)
+    if _feeds is not None:
+        _feeds[0 if "layer" in p else 1] += 1
     if getattr(wbits, "ndim", 0) >= 1 or getattr(abits, "ndim", 0) >= 1:
         return _serve_linear_rows(p, x, wbits, abits, _interp(interpret))
     bias = p.get("b")
@@ -427,7 +502,7 @@ def serve_linear(p: dict, x: jnp.ndarray, wbits=8, abits=8, *,
         return int4_linear(x, p["q4"], p["s"], bias, wbits=wbits,
                            abits=abits, interpret=interpret)
     return quant_linear(x, p["q"], p["s"], bias, wbits=wbits, abits=abits,
-                        interpret=interpret)
+                        layer=p.get("layer"), interpret=interpret)
 
 
 def serve_linear_stacked(p: dict, x: jnp.ndarray, wbits=8, abits=8, *,
@@ -506,7 +581,7 @@ def _serve_linear_rows(p, x, wbits, abits, interpret):
     accs, scales = [], []
     for f in uniq:
         accs.append(int8_accum(xq2, qw, f, from_bits=from_bits,
-                               interpret=interpret))
+                               layer=p.get("layer"), interpret=interpret))
         scales.append(jnp.broadcast_to(
             jnp.asarray(bf.effective_scale(p["s"], f, from_bits=from_bits),
                         jnp.float32).reshape(1, -1), (1, accs[-1].shape[-1])))

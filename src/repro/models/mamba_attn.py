@@ -17,9 +17,11 @@ out_proj; attention is ``models/transformer``'s, without RoPE when
 
 Parameters: ``{"mamba": leaves (n_mamba, ...), "attn": leaves
 (n_attn, ...)}`` in layer order; the forward scans over periods and
-unrolls one period.  Bit vectors have one entry per layer (both the
-mixer and the MLP of layer i run at bits[i]); the SSD scan and the state
-stay floating point (DESIGN.md §4).
+unrolls one period.  The int8 weight stacks are not cut into periods:
+mixer ``m`` of period ``i`` hands the GEMM the whole (n_mamba, K, N)
+stack and the index ``i * n_m + m`` (attention alike).  Bit vectors
+have one entry per layer (both the mixer and the MLP of layer i run at
+bits[i]); the SSD scan and the state stay floating point (DESIGN.md §4).
 
 Cache: ``{"kv": transformer cache (n_attn, B, ...), "ssm": (n_mamba, B,
 H, P, N) f32, "conv": (n_mamba, B, K-1, C), "live": (1, B) int32}``.
@@ -187,23 +189,32 @@ def forward(p, x, cfg, wvec, avec, *, positions, cache: Optional[dict] = None,
         return jax.tree.map(lambda a: a.reshape(n_per, k, *a.shape[1:]),
                             tree)
 
-    xs = {"mamba": periods(p["mamba"], nm), "attn": periods(p["attn"], na),
+    # the int8 weight stacks stay whole, (n_mamba, K, N) and (n_attn, K,
+    # N), and each layer's linears index them (tf.with_weight_stacks)
+    m_rest, m_stacks = tf.weight_stacks(p["mamba"])
+    a_rest, a_stacks = tf.weight_stacks(p["attn"])
+    xs = {"mamba": periods(m_rest, nm), "attn": periods(a_rest, na),
           "wb": periods(jnp.asarray(wvec), len(pat)),
           "ab": periods(jnp.asarray(avec), len(pat))}
+
+    def layer_params(sc, j, stacks, layer):
+        return tf.with_weight_stacks(
+            jax.tree.map(lambda a: a[j], sc), stacks, layer)
 
     def period(x, st, i, sc):
         mi = ai = 0
         for j, kind in enumerate(pat):
             wb, ab = sc["wb"][j], sc["ab"][j]
             if kind == "M":
-                lp = jax.tree.map(lambda a, m=mi: a[m], sc["mamba"])
-                x, st = _mamba(lp, x, cfg, wb, ab, st, i * nm + mi, valid,
-                               decode)
+                li = i * nm + mi
+                lp = layer_params(sc["mamba"], mi, m_stacks, li)
+                x, st = _mamba(lp, x, cfg, wb, ab, st, li, valid, decode)
                 mi += 1
             else:
-                lp = jax.tree.map(lambda a, m=ai: a[m], sc["attn"])
-                x, st = _attn(lp, x, cfg, wb, ab, positions, st, i * na + ai,
-                              t, valid)
+                li = i * na + ai
+                lp = layer_params(sc["attn"], ai, a_stacks, li)
+                x, st = _attn(lp, x, cfg, wb, ab, positions, st, li, t,
+                              valid)
                 ai += 1
         return x, st, ()
 

@@ -133,6 +133,47 @@ def _write_columns(cache: dict, layer, slots: jnp.ndarray,
     return out
 
 
+def _rebuild(tree, fn, path=()):
+    """``tree`` with ``fn(path, node)`` in place of each dict node for
+    which it returns a value; the dicts, tuples and lists around those
+    are rebuilt, every other leaf kept."""
+    if isinstance(tree, dict):
+        out = fn(path, tree)
+        if out is not None:
+            return out
+        return {k: _rebuild(v, fn, path + (k,)) for k, v in tree.items()}
+    if isinstance(tree, (tuple, list)):
+        return type(tree)(_rebuild(v, fn, path + (i,))
+                          for i, v in enumerate(tree))
+    return tree
+
+
+def weight_stacks(tree):
+    """Split the int8 weight stacks out of a layer-stacked params tree:
+    returns ``(rest, stacks)``, ``rest`` the tree without the "q" of each
+    linear whose container is an (L, K, N) stack, ``stacks`` those stacks
+    by the linear's path.  Deeper containers (MoE expert stacks) stay."""
+    stacks = {}
+
+    def take(path, node):
+        if getattr(node.get("q"), "ndim", 0) != 3:
+            return None
+        stacks[path] = node["q"]
+        return {k: v for k, v in node.items() if k != "q"}
+
+    return _rebuild(tree, take), stacks
+
+
+def with_weight_stacks(tree, stacks, layer):
+    """One layer's params from :func:`weight_stacks`' two parts: each
+    stacked linear becomes ``{"q": whole stack, "s": ..., "layer":
+    layer}``, which ``kops.serve_linear`` hands to the GEMM whole."""
+    if not stacks:
+        return tree
+    return _rebuild(tree, lambda path, node: (
+        dict(node, q=stacks[path], layer=layer) if path in stacks else None))
+
+
 def cached_layer_scan(body, x, xs, cache=None):
     """Scan ``body(x, cache, i, sc) -> (x, cache, y)`` over the leading
     axis of ``xs``; returns ``(x, cache, ys)``.
@@ -142,14 +183,20 @@ def cached_layer_scan(body, x, xs, cache=None):
     that layer changes (one column a row in decode), so XLA keeps the one
     buffer and updates it in place.  Scanning the cache as ``xs`` would
     slice a copy of each slab in and stack a new cache out, every step.
-    ``i`` (int32) indexes the cache's layer axis; ``cache`` None (the
-    training path) carries nothing."""
+    The int8 weight stacks stay out of the scanned ``xs`` for the same
+    reason: ``sc`` gives each (L, K, N) linear as its whole stack and
+    ``"layer": i`` (:func:`with_weight_stacks`), and the GEMM kernel
+    reads layer ``i``'s tiles from the stack, where a scanned container
+    would be a per-layer copy.  Scales, norms and other float leaves are
+    small and stay scanned.  ``i`` (int32) indexes the cache's layer
+    axis; ``cache`` None (the training path) carries nothing."""
     n = jax.tree.leaves(xs)[0].shape[0]
+    xs, stacks = weight_stacks(xs)
 
     def step(carry, scanned):
         x, cache = carry
         i, sc = scanned
-        x, cache, y = body(x, cache, i, sc)
+        x, cache, y = body(x, cache, i, with_weight_stacks(sc, stacks, i))
         return (x, cache), y
 
     (x, cache), ys = jax.lax.scan(

@@ -16,6 +16,7 @@ from __future__ import annotations
 
 import collections
 import dataclasses
+import functools
 import time
 from typing import Dict, Iterable, List, NamedTuple, Optional, Sequence, Tuple
 
@@ -23,6 +24,7 @@ import jax
 import numpy as np
 
 from repro.apsim import metrics as apm
+from repro.kernels import ops as kops
 
 SPAN_RING = 65_536      # events the span ring keeps (oldest dropped first)
 
@@ -84,11 +86,16 @@ class RuntimeStats:
     zero-retrace.
 
     Compiled programs are counted generically: an engine calls
-    ``stats.trace("prefill")`` inside the traced function, and readers
+    ``stats.trace("prefill")`` inside the traced function (or wraps it
+    in ``@stats.traced("prefill")``), and readers
     use the derived ``stats.prefill_traces`` / ``decode_traces`` /
     ``forward_traces`` attributes — any ``<program>_traces`` name reads
     the counter for ``<program>`` (0 if it never traced).  Each trace
     also leaves a ``trace.<program>`` mark, so a retrace names its tick.
+    A body wrapped by :meth:`traced` also records, in
+    ``weight_feeds[program]``, ``(from_stack, from_copy)`` for its latest
+    trace: the serve linears that took an (L, K, N) layer stack and its
+    index, and those given a 2-D weight (``kops.count_weight_feeds``).
 
     Spans (``with stats.span("admit.plan"): ...``) and marks are always
     recorded, into ``events``: a ring of the last :data:`SPAN_RING`
@@ -99,6 +106,7 @@ class RuntimeStats:
 
     def __init__(self) -> None:
         self.traces: Dict[str, int] = {}
+        self.weight_feeds: Dict[str, Tuple[int, int]] = {}
         self.tokens = 0                 # LM: tokens sampled
         self.admitted = 0               # LM: requests admitted into slots
         self.completed = 0              # LM: requests retired
@@ -122,6 +130,20 @@ class RuntimeStats:
     def trace(self, program: str) -> None:
         self.traces[program] = self.traces.get(program, 0) + 1
         self.mark("trace." + program)
+
+    def traced(self, program: str):
+        """Decorator for the body of a jitted program: every trace calls
+        :meth:`trace` and counts its serve linears' weight feeds."""
+        def wrap(fn):
+            @functools.wraps(fn)
+            def body(*args):
+                self.trace(program)
+                with kops.count_weight_feeds() as feeds:
+                    out = fn(*args)
+                self.weight_feeds[program] = tuple(feeds)
+                return out
+            return body
+        return wrap
 
     def span(self, name: str, rid: int = -1) -> _Span:
         """Context manager recording one host span (never use inside a

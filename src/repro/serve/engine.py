@@ -234,21 +234,20 @@ class ServeEngine(ServeRuntime):
         self._just_finished: List[int] = []
 
         # ---- compiled programs (each traces exactly once per shape)
+        @self.stats.traced("prefill")
         def _prefill_batch(q, batch, cache, wv, av):
-            self.stats.trace("prefill")
             return lm.prefill(q, batch, cfg, wv, av, cache)
 
+        @self.stats.traced("prefill")
         def _prefill_row(q, tokens, length, wv, av, *prefix):
-            self.stats.trace("prefill")
             cache = lm.empty_cache(cfg, 1, max_len)
             batch = {"tokens": tokens}
             if prefix:                  # vlm: (1, n_prefix_tokens, d)
                 batch["prefix"] = prefix[0]
             return lm.prefill(q, batch, cfg, wv, av, cache, lengths=length)
 
+        @self.stats.traced("decode")
         def _decode_scan(q, tok, t, cache, wv, av, temp, topk, keys):
-            self.stats.trace("decode")
-
             def step(carry, key):
                 tok, t, cache = carry
                 logits, cache = lm.decode_step(q, tok, t, cache, cfg, wv, av)
@@ -258,6 +257,7 @@ class ServeEngine(ServeRuntime):
             (tok, t, cache), toks = jax.lax.scan(step, (tok, t, cache), keys)
             return tok, t, cache, jnp.moveaxis(toks, 0, 1)   # (B, steps)
 
+        @self.stats.traced("draft")
         def _draft_scan(q, tok, t, cache, wv, av, temp, topk, keys):
             # speculative self-draft: SPEC_K_MAX scan-fused decode steps
             # at the engine's LOW draft bits (one program for every k —
@@ -265,7 +265,6 @@ class ServeEngine(ServeRuntime):
             # returns each draft's sampling density q_i: the rejection
             # verify tests p_i/q_i against the same distributions the
             # tokens were drawn from.
-            self.stats.trace("draft")
 
             def step(carry, key):
                 tok, t, cache = carry
@@ -282,6 +281,7 @@ class ServeEngine(ServeRuntime):
                     jnp.moveaxis(probs, 0, 1),       # (B, SPEC_K_MAX, V)
                     cache)
 
+        @self.stats.traced("verify")
         def _spec_verify(q, tok, draft_toks, draft_probs, t, cache,
                          wv, av, k_eff, temp, topk, key_u, key_s):
             # batched high-bit verify: ONE (SPEC_K_MAX + 1)-wide chunk
@@ -295,7 +295,6 @@ class ServeEngine(ServeRuntime):
             # the per-row accept clamp (min(spec_k, remaining - 1)), so
             # one compiled program covers every (slot, k, accept-length)
             # combination.
-            self.stats.trace("verify")
             B = tok.shape[0]
             U = SPEC_K_MAX + 1
             toks = jnp.concatenate([tok, draft_toks], axis=1)     # (B, U)
@@ -348,6 +347,7 @@ class ServeEngine(ServeRuntime):
         def _sample_first(logits, key, temp, topk):
             return _sample_tokens(logits[:, -1], key, temp, topk)
 
+        @self.stats.traced("extend")
         def _extend_row(q, tokens, row, start, r, wv, av):
             # partial prefix-cache hit: the entry's row holds a longer
             # (or equal) prompt — mask it down to its first ``start``
@@ -359,7 +359,6 @@ class ServeEngine(ServeRuntime):
             # clamped tail steps recompute the final token with
             # identical inputs (idempotent cache writes).  The entry's
             # pytree is never donated — the cache keeps its rows.
-            self.stats.trace("extend")
 
             def mask(path, p):
                 if path and path[-1] == "kpos":

@@ -3,6 +3,8 @@ kernels/ops.py — it must be BIT-EXACT against the pre-refactor inline
 math (the `_apply_linear1` serve branch, kept verbatim below as the
 oracle), and grouped per-row dispatch must be bit-exact against the
 per-row vmap baseline, for int8 and packed-int4 containers alike."""
+import functools
+
 import numpy as np
 import jax
 import jax.numpy as jnp
@@ -227,6 +229,80 @@ def test_serve_gemm_requant_on_tile_exact(rng, shape, container, traced,
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
     exact = np.asarray(x, np.int64) @ np.asarray(w_req, np.int64)
     np.testing.assert_array_equal(np.asarray(got), exact)
+
+
+# (M, K, N): aligned; N unaligned and stored K-minor (in the kernel as its
+# transpose); N unaligned with a K under 128 (stored N-minor, ragged)
+STACK_SHAPES = {"aligned": (48, 256, 384), "n_unaligned": (48, 256, 296),
+                "n_unaligned_small_k": (16, 64, 296)}
+
+
+@pytest.mark.parametrize("case", ["static8", "static4", "traced_shift",
+                                  "per_row"])
+@pytest.mark.parametrize("shape", list(STACK_SHAPES))
+def test_stacked_weight_matches_the_layer_slice(rng, case, shape):
+    """The stacked entry — the whole (L, K, N) container and a layer index
+    traced by a ``lax.scan`` — is bit-identical to the kernel on the
+    layer's own (K, N) slice, for static bits at 8 and 4 planes, traced
+    bits requantized on the tile (shift 4), and per-row bits through the
+    grouped path with two families."""
+    M, K, N = STACK_SHAPES[shape]
+    L = 3
+    w = jnp.asarray(rng.integers(-127, 128, (L, K, N)).astype(np.int8))
+    s = jnp.asarray(rng.uniform(0.01, 0.02, (L, 1, N)).astype(np.float32))
+    x = jnp.asarray(rng.normal(size=(M, K)).astype(np.float32))
+    xq = jnp.asarray(rng.integers(-127, 128, (M, K)).astype(np.int8))
+    wb_rows = jnp.asarray([4, 8] * (M // 2), jnp.int32)
+    bits = {"static8": 8, "static4": 4}.get(case)
+
+    def gemm(w, layer, i, tb):
+        tb = wb_rows if case == "per_row" else bits or tb
+        if case != "per_row":
+            return ops.int8_accum(xq, w, tb, layer=layer, interpret=True)
+        p = {"q": w, "s": s[i]}
+        if layer is not None:
+            p["layer"] = layer
+        with ops.bit_families((4, 8)):
+            return ops.serve_linear(p, x, tb, 8, interpret=True)
+
+    @functools.partial(jax.jit, static_argnums=0)
+    def scan(stacked, w, tb):
+        def body(_, i):
+            if stacked:
+                return (), gemm(w, i, i, tb)
+            return (), gemm(w[i], None, i, tb)       # the layer's slice
+        return jax.lax.scan(body, (), jnp.arange(L, dtype=jnp.int32))[1]
+
+    tb = jnp.asarray(4, jnp.int32)
+    got = scan(True, w, tb)
+    np.testing.assert_array_equal(np.asarray(got),
+                                  np.asarray(scan(False, w, tb)))
+    if case != "per_row":
+        for l in range(L):
+            exact = np.asarray(xq, np.int64) @ np.asarray(
+                bf.requant_shift(w[l], bits or 4), np.int64)
+            np.testing.assert_array_equal(np.asarray(got[l]), exact)
+
+
+def test_weight_feeds_count_each_linear(rng):
+    """``count_weight_feeds`` tells stacked linears from 2-D ones; a stack
+    whose K the kernel would pad is sliced first, and counted as such."""
+    x = jnp.asarray(rng.normal(size=(2, 128)).astype(np.float32))
+    s = jnp.ones((1, 256), jnp.float32)
+    stack = jnp.asarray(rng.integers(-127, 128, (3, 128, 256)), jnp.int8)
+    odd = jnp.asarray(rng.integers(-127, 128, (3, 300, 256)), jnp.int8)
+    x300 = jnp.asarray(rng.normal(size=(2, 300)).astype(np.float32))
+    with ops.count_weight_feeds() as feeds:
+        a = ops.serve_linear({"q": stack, "s": s, "layer": 1}, x)
+        ops.serve_linear({"q": stack[1], "s": s}, x)
+        b = ops.serve_linear({"q": odd, "s": s, "layer": 2}, x300)
+    assert feeds == [1, 2]
+    np.testing.assert_array_equal(
+        np.asarray(a), np.asarray(ops.serve_linear({"q": stack[1], "s": s},
+                                                   x)))
+    np.testing.assert_array_equal(
+        np.asarray(b), np.asarray(ops.serve_linear({"q": odd[2], "s": s},
+                                                   x300)))
 
 
 def test_int4_matmul_unaligned_falls_back(rng):

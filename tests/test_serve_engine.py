@@ -255,3 +255,26 @@ def test_engine_families_follow_controller(served):
     registered configurations (4- and 8-bit here)."""
     eng = _engine(served)
     assert eng._families == (4, 8)
+
+
+@pytest.mark.parametrize("arch,linears", [("qwen3_4b", 7),
+                                          ("granite_4_0_h_micro", 52)])
+def test_layer_scans_feed_the_gemm_whole_stacks(arch, linears):
+    """Both benchmark cells' models (smoke widths, the int8 menu): every
+    serve linear their decode and row-prefill programs trace takes the
+    layer stack whole with its layer index, none a per-layer 2-D copy
+    (qwen3-4b: seven linears a layer body; granite: a period unrolled,
+    nine Mamba-2 layers of five and one attention layer of seven)."""
+    cfg = configs.get_smoke(arch)
+    qparams = lm.init_serve_params(cfg, KEY)
+    ctrl = pol.BudgetController({"int8": pol.per_layer([8], [8], "int8")},
+                                {"int8": 1.0}, cfg.n_layers)
+    eng = ServeEngine(cfg, qparams, controller=ctrl, max_len=32, n_slots=2,
+                      prefill_len=8, decode_block=2)
+    rng = np.random.default_rng(0)
+    rids = [eng.submit(rng.integers(0, cfg.vocab_size, (n,)),
+                       max_new_tokens=3, budget_s=10.0) for n in (3, 6)]
+    res = eng.run()
+    assert all(len(res[r].tokens) == 3 for r in rids)
+    assert eng.stats.weight_feeds == {"prefill": (linears, 0),
+                                      "decode": (linears, 0)}

@@ -125,6 +125,20 @@ def test_ssm_update_compiles(one_chip):
         in call[0]
 
 
+@pytest.mark.parametrize("shape", [(36, 2048, 8512), (36, 2560, 9728),
+                                   (4, 2048, 512), (36, 2560, 1000),
+                                   (36, 300, 1000), (2, 100, 200)])
+def test_k_minor_matches_the_chips_default_layout(one_chip, shape):
+    """``ops._k_minor`` predicts the layout the TPU gives an int8 stack
+    (granite's in_proj is K-minor; aligned stacks are not; a tie goes
+    K-minor when N is unaligned), so the GEMM takes each stack in the
+    orientation it is stored in and XLA copies no stack to relayout it."""
+    out = jax.jit(lambda w: w + 1).lower(
+        _spec(shape, jnp.int8, one_chip)).compile()
+    k_minor = out.input_formats[0][0].layout.major_to_minor == (0, 2, 1)
+    assert k_minor == ops._k_minor(*shape[1:]), shape
+
+
 def _weight_producers(hlo: str):
     """(kernel, weight operand, instructions computing that operand) for
     each Mosaic call in compiled HLO text: the operand's own instruction,
@@ -143,11 +157,10 @@ def _weight_producers(hlo: str):
                                else define.group(1))
 
 
-def _compile_decode_block(one_chip, cfg, n_slots: int, max_len: int,
-                          decode_block: int):
-    """The serve engine's decode block (``ServeEngine._decode_scan``, the
-    int8 menu) compiled for the described chip from shapes alone; returns
-    (compiled, number of weight leaves, abstract cache)."""
+def _abstract_engine(one_chip, cfg, n_slots: int, max_len: int,
+                     decode_block: int, prefill_len: int = 64):
+    """A serve engine (the int8 menu) over abstract weights on the
+    described chip; returns (engine, weights, ``abstract``)."""
     from repro.models import lm
     from repro.serve.engine import ServeEngine
 
@@ -159,9 +172,22 @@ def _compile_decode_block(one_chip, cfg, n_slots: int, max_len: int,
         lambda: lm.init_serve_params(cfg, jax.random.PRNGKey(0))))
     ctl = pol.BudgetController({"int8": pol.per_layer([8], [8], "int8")},
                                {"int8": 1.0}, cfg.n_layers)
+    eng = ServeEngine(cfg, qp, max_len=max_len, controller=ctl,
+                      n_slots=n_slots, prefill_len=prefill_len,
+                      decode_block=decode_block)
+    return eng, qp, abstract
+
+
+def _compile_decode_block(one_chip, cfg, n_slots: int, max_len: int,
+                          decode_block: int):
+    """The serve engine's decode block (``ServeEngine._decode_scan``, the
+    int8 menu) compiled for the described chip from shapes alone; returns
+    (compiled, number of weight leaves, abstract cache)."""
+    from repro.models import lm
+
+    eng, qp, abstract = _abstract_engine(one_chip, cfg, n_slots, max_len,
+                                         decode_block)
     B, L = n_slots, cfg.n_layers
-    eng = ServeEngine(cfg, qp, max_len=max_len, controller=ctl, n_slots=B,
-                      prefill_len=64, decode_block=decode_block)
     cache = abstract(jax.eval_shape(lambda: lm.empty_cache(cfg, B, max_len)))
     keys = abstract(jax.eval_shape(
         lambda: jax.random.split(jax.random.PRNGKey(0), decode_block)))
@@ -180,11 +206,48 @@ def _compile_decode_block(one_chip, cfg, n_slots: int, max_len: int,
     return compiled, len(jax.tree.leaves(qp)), cache
 
 
+def _compile_prefill_row(one_chip, cfg, prefill_len: int, max_len: int):
+    """The serve engine's row prefill (``ServeEngine._prefill_row``, the
+    int8 menu) compiled for the described chip from shapes alone."""
+    eng, qp, _ = _abstract_engine(one_chip, cfg, 48, max_len, 8,
+                                  prefill_len)
+    L = cfg.n_layers
+    ops.set_force_pallas(True)
+    try:
+        with eng.compute_ctx():
+            return eng._prefill_row.lower(
+                qp, _spec((1, prefill_len), jnp.int32, one_chip),
+                _spec((1,), jnp.int32, one_chip),
+                _spec((L,), jnp.int32, one_chip),
+                _spec((L,), jnp.int32, one_chip)).compile()
+    finally:
+        ops.set_force_pallas(None, interpret=ops.INTERPRET_ENV)
+
+
+_DEFINE = re.compile(r"^(\w+)\[([\d,]*)\](\S*) ([\w\-]+)\(")
+
+
+def _stack_operand(body: str):
+    """(dtype, shape, how) of a kernel's weight operand from the line that
+    defines it: ``how`` is "held" for the program's own array (a
+    parameter, a loop's get-tuple-element of one, or a bitcast view of
+    either), "prefetched" for the compiler's copy of it into VMEM (memory
+    space S(1)), else the opcode."""
+    dtype, dims, layout, op = _DEFINE.match(body).groups()
+    shape = tuple(int(d) for d in dims.split(","))
+    if op in ("parameter", "get-tuple-element", "bitcast"):
+        return dtype, shape, "held"
+    if "S(1)" in layout and (op == "copy-done" or
+                             'custom_call_target="ConcatBitcast"' in body):
+        return dtype, shape, "prefetched"
+    return dtype, shape, op
+
+
 def test_serve_decode_reads_each_weight_as_stored(one_chip):
     """A two-layer serve decode step at qwen3-4b's widths (48 slots, the
     int8 menu): every linear is one Mosaic call, and no fusion feeding a
     kernel its weight requantizes it (no clamp, no arithmetic shift) —
-    the slice of the layer stack goes to the kernel as stored."""
+    the layer stack goes to the kernel as stored, whole."""
     cfg = dataclasses.replace(configs.get("qwen3_4b"), n_layers=2,
                               vocab_size=4096)
     hlo = _compile_decode_block(one_chip, cfg, 48, 128, 1)[0].as_text()
@@ -193,6 +256,83 @@ def test_serve_decode_reads_each_weight_as_stored(one_chip):
     for kernel, weight, body in found:
         for op in ("clamp", "shift-right-arithmetic"):
             assert op not in body, (kernel, weight, op)
+        dtype, shape, how = _stack_operand(body)
+        assert dtype == "s8" and len(shape) == 3 and shape[0] == 2, (
+            kernel, body[:120])
+        assert how in ("held", "prefetched"), (kernel, body[:120])
+
+
+def _weight_copies(hlo: str, least: int):
+    """Instructions outside fused computations that copy, pad, slice or
+    concatenate (or fuse such a move into) an s8 array of ``least`` bytes
+    or more in HBM: (name, op, dims).  Left out: the compiler's own
+    prefetches into VMEM (an output in memory space S(1)), which it may
+    make of a stack small enough to hold there whole."""
+    comps = dict(re.findall(r"^(?:ENTRY )?%?([\w.\-]+) [^\n]*\{\n(.*?)\n\}",
+                            hlo, re.S | re.M))
+    fused = set(re.findall(r"kind=k\w+, calls=%([\w.\-]+)", hlo))
+    moves = {"fusion", "copy", "copy-done", "pad", "slice", "dynamic-slice",
+             "slice-done", "concatenate"}
+    inst = re.compile(r"^\s*(?:ROOT )?%([\w.\-]+) = (.*?) ([\w\-]+)\(")
+    for name, body in comps.items():
+        if name in fused:
+            continue
+        for line in body.splitlines():
+            m = inst.match(line)
+            if not m:
+                continue
+            op = m.group(3)
+            if op == "custom-call":
+                if "tpu_custom_call" in line:
+                    continue
+            elif op not in moves:
+                continue
+            for dims, layout in re.findall(r"s8\[([\d,]+)\](\{[^}]*\})?",
+                                           m.group(2)):
+                shape = [int(d) for d in dims.split(",")]
+                if int(np.prod(shape)) >= least and "S(1)" not in layout:
+                    yield m.group(1), op, shape
+
+
+@pytest.mark.parametrize("arch,n_layers,program", [
+    ("qwen3_4b", 2, "decode"), ("granite_4_0_h_micro", 20, "decode"),
+    ("qwen3_4b", 2, "prefill")])
+def test_gemm_reads_weights_from_the_layer_stack(one_chip, arch, n_layers,
+                                                 program):
+    """The cells' decode block (48 slots, decode_block 8) and qwen3-4b's
+    row prefill (512 positions), compiled at full widths with fewer
+    layers: every serve GEMM's weight operand is a whole (L, K, N) int8
+    stack as the program holds it (a parameter, or the loop's
+    get-tuple-element of one), and no fusion, copy, pad, slice or other
+    custom call makes an s8 array in HBM as large as one layer's
+    smallest weight — no per-layer weight copy anywhere in the step.
+
+    The compiler may still prefetch a stack small enough for VMEM whole
+    (granite's four-layer attention stacks; at two layers, qwen3-4b's
+    too): the kernel then reads the layer's tiles from that copy."""
+    from repro.models import lm
+
+    cfg = dataclasses.replace(configs.get(arch), n_layers=n_layers)
+    max_len = 768 if arch == "qwen3_4b" else 2560
+    if program == "decode":
+        compiled = _compile_decode_block(one_chip, cfg, 48, max_len, 8)[0]
+    else:
+        compiled = _compile_prefill_row(one_chip, cfg, 512, max_len)
+    stacks = {tuple(a.shape) for a in jax.tree.leaves(jax.eval_shape(
+        lambda: lm.init_serve_params(cfg, jax.random.PRNGKey(0))))
+        if a.dtype == jnp.int8 and a.ndim == 3}
+    # a K-minor stack (granite's in_proj) goes as its (L, N, K) transpose
+    stacks |= {(L, N, K) for L, K, N in stacks if ops._k_minor(K, N)}
+    hlo = compiled.as_text()
+    found = [(k, body) for k, _, body in _weight_producers(hlo)
+             if k.startswith("bitplane_matmul")]
+    assert found
+    for kernel, body in found:
+        dtype, shape, how = _stack_operand(body)
+        assert dtype == "s8" and shape in stacks, (kernel, body[:120])
+        assert how in ("held", "prefetched"), (kernel, body[:120])
+    least = min(k * n for _, k, n in stacks)
+    assert list(_weight_copies(hlo, least)) == []
 
 
 _BYTES = {"pred": 1, "s8": 1, "u8": 1, "bf16": 2, "s32": 4, "u32": 4,
